@@ -402,10 +402,10 @@ def main():
 
     print("\n== observability: tracing, metrics, query log (PR 10) ==")
     # `obs.tracing` (or REPRO_OBS_TRACING=1) records a structured
-    # QueryTrace per query: pipeline-stage spans, the WLM admission wait,
-    # every DAG vertex split into compute / exchange-wait / spill-I/O,
-    # shuffle lanes, federated split reads, kernel dispatches, and
-    # serving/adaptive events — all on one clock.  Tracing off costs one
+    # QueryTrace per query: pipeline-stage spans, the worker and WLM
+    # admission waits, every DAG vertex split into compute / exchange-wait
+    # / spill-I/O, shuffle lanes, federated split reads, kernel round trips,
+    # LLAP reads, and serving/adaptive events — all on one clock.  Tracing off costs one
     # attribute test per site (the span helpers return a shared no-op).
     traced = db.connect(warehouse=conn.warehouse, result_cache=False,
                         **{"obs.tracing": True, "shuffle.partitions": 2})

@@ -3,13 +3,15 @@
 Three coupled pieces, shared by every telemetry surface:
 
   * :mod:`.trace` — structured per-query tracing: a :class:`QueryTrace`
-    of nested spans and point events (pipeline stages, WLM admission
-    wait, DAG vertices split into compute / exchange-wait / spill-I/O,
-    shuffle lanes, federated split reads, kernel dispatches, serving and
+    of nested spans and point events (pipeline stages, worker and WLM
+    admission waits, DAG vertices split into compute / exchange-wait /
+    spill-I/O, shuffle lanes, federated split reads, kernel round trips
+    and their host-to-device bytes, LLAP chunk reads, serving and
     adaptive events), exportable as Chrome trace-event JSON for
-    Perfetto.  ``make_span`` / ``emit_event`` follow the lockdep factory
-    pattern: plain no-op singletons when ``obs.tracing`` is off, one
-    attribute test on the hot path.
+    Perfetto, and mirrored into an active ``jax.profiler`` trace.
+    ``make_span`` / ``emit_event`` follow the lockdep factory pattern:
+    plain no-op singletons when ``obs.tracing`` is off, one attribute
+    test on the hot path.
   * :mod:`.metrics` — the warehouse :class:`MetricsRegistry` (counters /
     gauges / bucketed histograms); ``poll()``, ``server_stats()`` and the
     WLM/serving/shuffle counters keep their dict shapes but derive from
@@ -32,15 +34,17 @@ from ...analysis.lockdep import make_lock
 from . import clock
 from .metrics import DEFAULT_BUCKETS_MS, Counter, Histogram, MetricsRegistry
 from .query_log import QueryLog
-from .trace import (NOOP_SPAN, QueryTrace, close_vertex_frame, emit_event,
-                    make_span, note_exchange_wait, note_spill_io,
+from .trace import (NOOP_SPAN, QueryTrace, close_vertex_frame, current_trace,
+                    emit_event, make_kernel_span, make_span,
+                    note_exchange_wait, note_h2d, note_spill_io,
                     open_vertex_frame, tracing_enabled)
 
 __all__ = [
     "DEFAULT_BUCKETS_MS", "Counter", "Histogram", "MetricsRegistry",
     "NOOP_SPAN", "QueryLog", "QueryTrace", "WarehouseObs", "clock",
-    "close_vertex_frame", "emit_event", "make_span", "note_exchange_wait",
-    "note_spill_io", "open_vertex_frame", "tracing_enabled",
+    "close_vertex_frame", "current_trace", "emit_event", "make_kernel_span",
+    "make_span", "note_exchange_wait", "note_h2d", "note_spill_io",
+    "open_vertex_frame", "tracing_enabled",
 ]
 
 

@@ -1,12 +1,19 @@
 """Structured per-query tracing (spans + point events, Chrome-exportable).
 
 One :class:`QueryTrace` collects everything a single query does — pipeline
-stages, the WLM admission wait, every DAG vertex (split into compute vs.
-exchange-wait vs. spill-I/O time), shuffle lanes, federated split reads,
-kernel dispatches, serving-tier attach/hit and adaptive decisions — on one
-shared clock (:mod:`.clock`), and exports the lot as Chrome trace-event
-JSON (``QueryHandle.trace()`` / ``Connection.export_trace``) so a query
-renders directly in Perfetto / ``chrome://tracing``.
+stages, the wait for a query worker and for WLM admission, every DAG vertex
+(split into compute vs. exchange-wait vs. spill-I/O time), shuffle lanes,
+federated split reads, kernel round trips with their host-to-device bytes,
+LLAP chunk reads and the scan's wait for them, serving-tier attach/hit and
+adaptive decisions — on one shared clock (:mod:`.clock`), and exports the
+lot as Chrome trace-event JSON (``QueryHandle.trace()`` /
+``Connection.export_trace``) so a query renders directly in Perfetto /
+``chrome://tracing``.
+
+While a ``jax.profiler`` session is active, every live span of a traced
+query also opens a ``jax.profiler.TraceAnnotation`` of the same name on its
+thread, so the program's spans land in the profiler's trace on the same
+clock as the device's ops.  Names never start with ``bench.``.
 
 Hot-path discipline follows the lockdep factory pattern: tracing resolves
 to a per-query ``trace`` object exactly once (``None`` when ``obs.tracing``
@@ -20,11 +27,16 @@ frame (:func:`open_vertex_frame`), the exchange layer accumulates blocking
 wait and spill-I/O durations into it (:func:`note_exchange_wait` /
 :func:`note_spill_io`), and the scheduler folds the frame into the vertex
 record at completion.  Accumulation outside an open frame (e.g. the client
-thread draining the root exchange) is silently dropped.
+thread draining the root exchange) is silently dropped.  The frame also
+carries the query's trace (:func:`current_trace`) to code that has no
+``ExecContext``, such as the scan's I/O loop.  A kernel span is itself a
+thread-local frame: the kernel wrappers add the bytes they hand to the
+device to it (:func:`note_h2d`).
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from typing import Dict, List, Optional
 
@@ -67,6 +79,15 @@ def make_span(trace: Optional["QueryTrace"], name: str, cat: str = "span",
     return trace.span(name, cat, **args)
 
 
+def make_kernel_span(trace: Optional["QueryTrace"], kernel: str,
+                     engine: str):
+    """A ``kernel.<kernel>`` round-trip span on ``trace`` that gathers the
+    host-to-device bytes of the call, or the shared no-op."""
+    if trace is None:
+        return NOOP_SPAN
+    return _KernelSpan(trace, kernel, engine)
+
+
 def emit_event(trace: Optional["QueryTrace"], name: str, cat: str = "event",
                **args) -> None:
     """Record a point event; no-op (no allocation) when tracing is off."""
@@ -76,19 +97,21 @@ def emit_event(trace: Optional["QueryTrace"], name: str, cat: str = "event",
 
 # -------------------------------------------------- thread-local accounting
 class _VertexFrame:
-    __slots__ = ("wait_s", "spill_s")
+    __slots__ = ("wait_s", "spill_s", "trace")
 
-    def __init__(self):
+    def __init__(self, trace: Optional["QueryTrace"]):
         self.wait_s = 0.0
         self.spill_s = 0.0
+        self.trace = trace
 
 
 _tls = threading.local()
 
 
-def open_vertex_frame() -> _VertexFrame:
-    """Start exchange-wait / spill-I/O accounting on this thread."""
-    frame = _VertexFrame()
+def open_vertex_frame(trace: Optional["QueryTrace"] = None) -> _VertexFrame:
+    """Start exchange-wait / spill-I/O accounting on this thread, for the
+    query ``trace``."""
+    frame = _VertexFrame(trace)
     _tls.frame = frame
     return frame
 
@@ -109,11 +132,46 @@ def note_spill_io(seconds: float) -> None:
         frame.spill_s += seconds
 
 
-# ------------------------------------------------------------------- spans
-class _Span:
-    """A live span: context manager recording a completed interval."""
+def current_trace() -> Optional["QueryTrace"]:
+    """The trace of the vertex running on this thread (None: untraced)."""
+    frame = getattr(_tls, "frame", None)
+    return frame.trace if frame is not None else None
 
-    __slots__ = ("_trace", "name", "cat", "args", "_t0")
+
+def note_h2d(*arrays) -> None:
+    """Add the bytes of ``arrays``, handed to a jitted program, to the
+    kernel span open on this thread; dropped when none is."""
+    span = getattr(_tls, "kernel", None)
+    if span is not None:
+        span.h2d_bytes += sum(int(a.nbytes) for a in arrays)
+
+
+# ---------------------------------------------------- profiler annotations
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name`` while a
+    profiler session is active, else None.  A process that has not
+    imported jax has no profiler session, so jax is never imported here."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    if not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+# ------------------------------------------------------------------- spans
+#: name prefix of the kernel round-trip spans: ``kernel.<registry name>``
+KERNEL_SPAN = "kernel."
+
+
+class _Span:
+    """A live span: context manager recording a completed interval, and
+    mirroring it as a profiler annotation while a session is active."""
+
+    __slots__ = ("_trace", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, trace: "QueryTrace", name: str, cat: str, args: dict):
         self._trace = trace
@@ -121,15 +179,42 @@ class _Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        self._ann = _profiler_annotation(self.name)
         self._t0 = clock.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._trace.add_span(self.name, self.cat, self._t0,
-                             clock.perf_counter(), **self.args)
+        t1 = clock.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._trace.add_span(self.name, self.cat, self._t0, t1, **self.args)
         return False
+
+
+class _KernelSpan(_Span):
+    """One kernel round trip, from the call until its result is a host
+    array; the thread's kernel frame while open (:func:`note_h2d`)."""
+
+    __slots__ = ("h2d_bytes", "_outer")
+
+    def __init__(self, trace: "QueryTrace", kernel: str, engine: str):
+        super().__init__(trace, KERNEL_SPAN + kernel, "kernel",
+                         {"engine": engine})
+        self.h2d_bytes = 0
+        self._outer = None
+
+    def __enter__(self) -> "_KernelSpan":
+        self._outer = getattr(_tls, "kernel", None)
+        _tls.kernel = self
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        _tls.kernel = self._outer
+        self.args["h2d_bytes"] = self.h2d_bytes
+        return super().__exit__(*exc)
 
 
 class QueryTrace:
@@ -151,7 +236,6 @@ class QueryTrace:
         # (name, cat, ts, track, args)
         self._events: List[tuple] = []
         self.vertices: Dict[str, dict] = {}
-        self.kernels: Dict[str, int] = {}
 
     # -- recording ----------------------------------------------------------
     def span(self, name: str, cat: str = "span", **args) -> _Span:
@@ -170,18 +254,6 @@ class QueryTrace:
             track = threading.get_ident()
         with self._lock:
             self._spans.append((name, cat, t_begin, t_end, track, args))
-
-    def kernel_dispatch(self, name: str, engine: str) -> None:
-        """Count a kernel-registry dispatch; first occurrence of each
-        (kernel, engine) pair also drops a point event on the timeline."""
-        key = f"{name}[{engine}]"
-        with self._lock:
-            seen = self.kernels.get(key, 0)
-            self.kernels[key] = seen + 1
-            if seen == 0:
-                self._events.append(
-                    (f"kernel:{key}", "kernel", clock.perf_counter(),
-                     threading.get_ident(), {}))
 
     def add_vertex(self, vid: str, t_begin: float, seconds: float,
                    wait_s: float = 0.0, spill_s: float = 0.0, rows: int = 0,
@@ -214,11 +286,22 @@ class QueryTrace:
             spans = list(self._spans)
             events = list(self._events)
             vertices = {k: dict(v) for k, v in self.vertices.items()}
-            kernels = dict(self.kernels)
         stages = {
             name.split(":", 1)[1]: round((t1 - t_b) * 1e3, 3)
             for name, cat, t_b, t1, _track, _a in spans if cat == "stage"
         }
+        # kernel round trips per "kernel[engine]"; other spans summed by name
+        kernels: Dict[str, list] = {}
+        span_s: Dict[str, float] = {}
+        for name, cat, t_b, t1, _track, a in spans:
+            if cat == "kernel":
+                key = f"{name[len(KERNEL_SPAN):]}[{a['engine']}]"
+                k = kernels.setdefault(key, [0, 0.0, 0])
+                k[0] += 1
+                k[1] += t1 - t_b
+                k[2] += a["h2d_bytes"]
+            elif cat != "stage":
+                span_s[name] = span_s.get(name, 0.0) + (t1 - t_b)
         verts = {
             vid: {
                 "total_ms": round(r["seconds"] * 1e3, 3),
@@ -240,7 +323,13 @@ class QueryTrace:
                 for name, cat, ts, _track, args in sorted(
                     events, key=lambda e: e[2])
             ],
-            "kernel_dispatches": kernels,
+            "kernel_dispatches": {k: n for k, (n, _s, _b) in kernels.items()},
+            "kernels": {k: {"calls": n, "mean_us": round(t / n * 1e6, 3),
+                            "h2d_bytes": b}
+                        for k, (n, t, b) in sorted(kernels.items())},
+            "kernel_h2d_bytes": sum(b for _n, _t, b in kernels.values()),
+            "spans_ms": {n: round(t * 1e3, 3)
+                         for n, t in sorted(span_s.items())},
         }
 
     def to_chrome(self) -> dict:

@@ -484,7 +484,8 @@ class DAGScheduler:
                     mn.source = (adaptive.source_for(vid, mn, src)
                                  if adaptive is not None else src)
                 t0 = clock.perf_counter()
-                frame = open_vertex_frame() if trace is not None else None
+                frame = (open_vertex_frame(trace) if trace is not None
+                         else None)
                 rows: Optional[int] = None
                 if vid in shareable:
                     key, table = shareable[vid]

@@ -23,7 +23,7 @@ import numpy as np
 from ..acid import AcidTable
 from ..bloomfilter import BloomFilter
 from ..metastore import Metastore, Snapshot, WriteIdList
-from ..obs.trace import make_span
+from ..obs.trace import make_kernel_span, make_span
 from ..optimizer import plan as P
 from ..sql import ast as A
 from ..storage import SargPredicate
@@ -79,13 +79,18 @@ class ExecContext:
     def record(self, node: P.PlanNode, rows: int) -> None:
         self.op_stats[node.digest()] = rows
 
-    def kernel(self, name: str):
-        """Resolve a compute kernel for this query's engine selection."""
+    def kernel_call(self, name: str, *args):
+        """Run the registry kernel ``name`` under this query's engine; the
+        result comes back as host arrays, inside a ``kernel.<name>`` span
+        (the round trip, fetch included) when the query is traced."""
         from ...kernels.registry import resolve
 
-        if self.trace is not None:
-            self.trace.kernel_dispatch(name, self.engine)
-        return resolve(name, self.engine)
+        fn = resolve(name, self.engine)
+        with make_kernel_span(self.trace, name, self.engine):
+            out = fn(*args)
+            if isinstance(out, tuple):
+                return tuple(np.asarray(a) for a in out)
+            return np.asarray(out)
 
 
 # ===========================================================================
@@ -365,15 +370,15 @@ class _KernelBloomProbe:
     registry (``bloom_probe`` under ``engine: pallas|ref``) while presenting
     the ``might_contain`` surface the scan I/O layer expects."""
 
-    def __init__(self, bf: BloomFilter, engine: str):
+    def __init__(self, bf: BloomFilter, ctx: ExecContext):
         self._bf = bf
-        self._engine = engine
+        self._ctx = ctx
 
     def might_contain(self, values: np.ndarray) -> np.ndarray:
-        from ...kernels.bloom.ops import probe_bloom_filter
+        from ...kernels.bloom.ops import bloom_operands
 
-        return np.asarray(probe_bloom_filter(self._bf, values,
-                                             engine=self._engine))
+        return self._ctx.kernel_call("bloom_probe",
+                                     *bloom_operands(self._bf, values))
 
 
 class _BuildTable:
@@ -436,8 +441,8 @@ class _BuildTable:
             u32, v32 = uniq.astype(np.float32), vals.astype(np.float32)
             if (np.array_equal(u32.astype(uniq.dtype), uniq)
                     and np.array_equal(v32.astype(vals.dtype), vals)):
-                fn = self.ctx.kernel("key_lookup")
-                return np.asarray(fn(u32, v32)).astype(np.int64)
+                return self.ctx.kernel_call("key_lookup", u32,
+                                            v32).astype(np.int64)
         idx = np.minimum(np.searchsorted(uniq, vals), len(uniq) - 1)
         found = uniq[idx] == vals
         return np.where(found, idx, -1).astype(np.int64)
@@ -537,7 +542,7 @@ class Executor:
                 bloom = res["bloom"]
                 if self.ctx.engine != "auto":
                     # route stripe-level probes through the kernel registry
-                    bloom = _KernelBloomProbe(bloom, self.ctx.engine)
+                    bloom = _KernelBloomProbe(bloom, self.ctx)
                 runtime_blooms[rf.target_column] = bloom
                 sargs.append(SargPredicate(rf.target_column, ">=", res["min"]))
                 sargs.append(SargPredicate(rf.target_column, "<=", res["max"]))
@@ -706,8 +711,8 @@ class Executor:
             compiled = _compile_kernel_filter(predicate, b)
             if compiled is not None:
                 cols, ops, lits = compiled
-                fn = self.ctx.kernel("filter_eval")
-                return np.asarray(fn(cols, ops, lits)).astype(bool)
+                return self.ctx.kernel_call("filter_eval", cols, ops,
+                                            lits).astype(bool)
         return eval_expr(predicate, b, self.ctx).astype(bool)
 
     def _stream_project(self, node: P.Project):
@@ -1066,8 +1071,8 @@ class Executor:
         if not np.array_equal(f32.astype(vals.dtype), vals):
             return None
         if spec.fn in ("min", "max"):
-            fn = self.ctx.kernel("hash_group_minmax")
-            mins, maxs = fn(codes.astype(np.int32), f32, int(ng))
+            mins, maxs = self.ctx.kernel_call(
+                "hash_group_minmax", codes.astype(np.int32), f32, int(ng))
             out = np.asarray(mins if spec.fn == "min" else maxs,
                              dtype=np.float64)
             counts = np.bincount(codes, minlength=ng)
@@ -1080,8 +1085,8 @@ class Executor:
             # bounded by sum(|v|), so < 2^24 keeps float32 accumulation exact
             if float(np.abs(vals.astype(np.int64)).sum()) >= float(1 << 24):
                 return None
-        fn = self.ctx.kernel("hash_group")
-        sums, counts = fn(codes.astype(np.int32), f32, int(ng))
+        sums, counts = self.ctx.kernel_call(
+            "hash_group", codes.astype(np.int32), f32, int(ng))
         if spec.fn == "count":
             return np.asarray(counts, dtype=np.int64)
         sums = np.asarray(sums, dtype=np.float64)

@@ -30,6 +30,7 @@ import numpy as np
 
 from ...analysis.lockdep import make_rlock
 from ..bloomfilter import BloomFilter
+from ..obs.trace import QueryTrace, current_trace, make_span
 from ..storage import (
     FileMeta,
     SargPredicate,
@@ -96,14 +97,16 @@ class LlapDaemon:
 
     # ------------------------------------------------------------- chunks
     def _get_chunk(self, f: StripeFile, meta: FileMeta, stripe: int,
-                   col: str) -> np.ndarray:
+                   col: str, trace: Optional[QueryTrace] = None
+                   ) -> np.ndarray:
         key = (meta.file_id, stripe, col)
         with self._lock:
             if key in self._chunks:
                 self.counters["cache_hits"] += 1
                 self._policy.on_access(key)
                 return self._chunks[key]
-        arr = f.read_column(stripe, col)
+        with make_span(trace, "llap.read", "llap"):
+            arr = f.read_column(stripe, col)
         nbytes = arr.nbytes
         with self._lock:
             self.counters["cache_misses"] += 1
@@ -190,16 +193,20 @@ class LlapIO:
                 continue
             wanted_stripes.append(si)
 
+        # the I/O threads have no ExecContext: the consumer hands them the
+        # trace of the vertex it runs for
+        trace = current_trace()
         with StripeFile(path) as f:
             def load(si: int) -> Dict[str, np.ndarray]:
-                return {c: self.daemon._get_chunk(f, meta, si, c)
+                return {c: self.daemon._get_chunk(f, meta, si, c, trace)
                         for c in cols}
 
             futures = [self.daemon.io_pool.submit(load, si)
                        for si in wanted_stripes]
             try:
                 for fut in futures:
-                    stripe_cols = fut.result()
+                    with make_span(trace, "scan.io_wait", "scan"):
+                        stripe_cols = fut.result()
                     self.daemon.counters["stripes_read"] += 1
                     yield _bloom_masked(stripe_cols, cols, runtime_blooms)
             finally:

@@ -26,6 +26,7 @@ from queue import Empty, Full, Queue
 from typing import Dict, Iterator, Optional, Tuple
 
 from ...analysis.lockdep import make_condition, make_lock
+from ..obs import clock
 from ..obs.trace import QueryTrace, emit_event, make_span, tracing_enabled
 from ..sql import ast as A
 from .cancel import CancelToken, QueryCancelledError
@@ -206,6 +207,7 @@ class QueryTask:
         # one attribute test and allocates no span objects
         self.trace = QueryTrace(qid, sql) if tracing_enabled(config) else None
         self.submitted_at = time.time()
+        self.submitted_clock = clock.perf_counter()  # sched:worker_wait
         self.admitted_at: Optional[float] = None
         self.wlm = None                        # set by QueryScheduler.submit
         self.serving_stats = None              # set by QueryScheduler.submit
@@ -388,6 +390,11 @@ class QueryScheduler:
 
     # ------------------------------------------------------------- worker
     def _run(self, session, task: QueryTask) -> None:
+        if task.trace is not None:
+            # submit ran on the client's thread: record the wait for this
+            # worker as a finished interval
+            task.trace.add_span("sched:worker_wait", "sched",
+                                task.submitted_clock, clock.perf_counter())
         wlm = self.wh.wlm
         admitted = False
         cache_hit = False

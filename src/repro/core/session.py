@@ -499,7 +499,8 @@ class Session:
     @staticmethod
     def _analyze_trace_lines(q: QueryContext) -> List[str]:
         """Trace-derived EXPLAIN ANALYZE sections: per-vertex wall split,
-        shuffle-lane skew, and the serving/kernel event log."""
+        shuffle-lane skew, kernel round trips and the serving/adaptive/WLM
+        event log."""
         if q.trace is None:
             return []
         summ = q.trace.summary()
@@ -523,11 +524,13 @@ class Session:
                         f"    lanes={len(rows)}"
                         f" rows/lane min={min(rows)} max={max(rows)}"
                         f" skew={skew:.2f}x")
-        dispatches = summ.get("kernel_dispatches", {})
-        if dispatches:
+        kernels = summ.get("kernels", {})
+        if kernels:
             lines.append("kernel dispatches:")
-            for name, n in sorted(dispatches.items()):
-                lines.append(f"  {name}: {n}")
+            for name, k in kernels.items():
+                lines.append(f"  {name}: calls={k['calls']}"
+                             f" mean_round_trip={k['mean_us']:.1f} us"
+                             f" h2d={k['h2d_bytes'] / 1e6:.3f} MB")
         events = [ev for ev in summ.get("events", [])
                   if ev.get("cat") in ("serving", "adaptive", "wlm")]
         if events:

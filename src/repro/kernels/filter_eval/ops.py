@@ -6,6 +6,7 @@ import functools
 import jax
 import numpy as np
 
+from ...core.obs.trace import note_h2d
 from ..registry import bucket, interpret_mode, padded, register, resolve
 from .filter_eval import filter_eval_pallas
 from .ref import filter_eval_ref
@@ -21,7 +22,9 @@ def _filter_eval_jit(columns, ops: tuple, lits: tuple):
 def _filter_eval_pallas(columns, ops: tuple, lits: tuple) -> np.ndarray:
     n = len(columns[0])
     rows = bucket(n)
-    mask = _filter_eval_jit(tuple(padded(c, rows) for c in columns), ops, lits)
+    columns = tuple(padded(c, rows) for c in columns)
+    note_h2d(*columns)
+    mask = _filter_eval_jit(columns, ops, lits)
     return np.asarray(mask)[:n]
 
 

@@ -6,6 +6,7 @@ import functools
 import jax
 import numpy as np
 
+from ...core.obs.trace import note_h2d
 from ..registry import (GROUP_BUCKET_FLOOR, bucket, interpret_mode, padded,
                         register, resolve)
 from .hash_group import hash_group_minmax_pallas, hash_group_pallas
@@ -28,8 +29,9 @@ def _bucketed(jitted, codes, values, num_groups: int):
     """Pad rows (code -1: no group) and the group domain to their buckets,
     then cut the per-group results back to ``num_groups``."""
     rows = bucket(len(codes))
-    out = jitted(padded(codes, rows, -1), padded(values, rows),
-                 bucket(num_groups, GROUP_BUCKET_FLOOR))
+    codes, values = padded(codes, rows, -1), padded(values, rows)
+    note_h2d(codes, values)
+    out = jitted(codes, values, bucket(num_groups, GROUP_BUCKET_FLOOR))
     return tuple(np.asarray(a)[:num_groups] for a in out)
 
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from ...core.obs.trace import note_h2d
 from ..registry import bucket, interpret_mode, padded, register, resolve
 from .key_lookup import key_lookup_pallas
 from .ref import key_lookup_ref
@@ -21,7 +22,9 @@ def _key_lookup_pallas(sorted_vals, probe) -> np.ndarray:
         return np.full(n, -1, dtype=np.int32)
     # NaN pads the dictionary: it never matches nor sorts below a probe
     table = padded(np.asarray(sorted_vals, np.float32), bucket(g), np.nan)
-    codes = _key_lookup_jit(table, padded(probe, bucket(n)))
+    probe = padded(probe, bucket(n))
+    note_h2d(table, probe)
+    codes = _key_lookup_jit(table, probe)
     return np.asarray(codes)[:n]
 
 

@@ -303,3 +303,161 @@ class TestExplainAnalyze:
             cur = conn.execute("EXPLAIN ANALYZE SELECT COUNT(*) FROM ev")
             text = "\n".join(r[0] for r in cur.fetchall())
             assert "vertex breakdown:" in text
+
+
+# ===========================================================================
+# kernel round trips, host-to-device bytes, scan reads, profiler mirror
+# ===========================================================================
+def _load_star(conn):
+    conn.execute("CREATE TABLE fact (k BIGINT, d BIGINT, v BIGINT)")
+    conn.execute("CREATE TABLE dim (d BIGINT, name STRING)")
+    conn.execute("INSERT INTO fact VALUES " + ", ".join(
+        f"({i}, {i % 50}, {i % 13})" for i in range(3000)))
+    conn.execute("INSERT INTO dim VALUES " + ", ".join(
+        f"({i}, 'n{i % 3}')" for i in range(50)))
+
+
+STAR_SQL = ("SELECT dim.name, SUM(fact.v) FROM fact JOIN dim "
+            "ON fact.d = dim.d WHERE fact.k > 10 GROUP BY dim.name")
+STAR_ANSWER = sorted(
+    (f"n{g}", sum(i % 13 for i in range(11, 3000) if i % 50 % 3 == g))
+    for g in range(3))
+
+
+def _forbid_spans(monkeypatch):
+    from repro.core.obs import trace as trace_mod
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a span was allocated with tracing off")
+
+    monkeypatch.setattr(trace_mod._Span, "__init__", refuse)
+
+
+class TestKernelSpans:
+    def test_untraced_kernel_call_allocates_no_span(self, monkeypatch):
+        from repro.core.obs.trace import make_kernel_span
+        from repro.core.runtime.exec import ExecContext
+
+        assert make_kernel_span(None, "key_lookup", "pallas") is NOOP_SPAN
+        _forbid_spans(monkeypatch)
+        ctx = ExecContext(None, None, config={"engine": "pallas"})
+        assert ctx.trace is None
+        codes = ctx.kernel_call(
+            "key_lookup", np.array([1.0, 3.0, 5.0], np.float32),
+            np.array([3.0, 4.0, 5.0], np.float32))
+        assert isinstance(codes, np.ndarray)
+        assert codes.tolist() == [1, -1, 2]
+
+    def test_untraced_pallas_query_allocates_no_span(self, wh_dir,
+                                                     monkeypatch):
+        # every kernel, LLAP and scan site takes the no-op when untraced
+        with db.connect(wh_dir, engine="pallas") as conn:
+            _load_star(conn)
+            _forbid_spans(monkeypatch)
+            h = conn.execute_async(STAR_SQL)
+            assert sorted(h.result().fetchall()) == STAR_ANSWER
+            assert h._task.trace is None
+
+    def test_pallas_query_records_kernel_and_scan_spans(self, wh_dir):
+        with db.connect(wh_dir, engine="pallas", **TRACED) as conn:
+            _load_star(conn)
+            h = conn.execute_async(STAR_SQL)
+            assert sorted(h.result().fetchall()) == STAR_ANSWER
+            trace = h._task.trace
+            names = {name for name, *_rest in trace._spans}
+            for span in ("kernel.filter_eval", "kernel.key_lookup",
+                         "llap.read", "scan.io_wait", "sched:worker_wait"):
+                assert span in names, (span, sorted(names))
+            summ = trace.summary()
+            kernels = summ["kernels"]
+            # the dispatch counts now derive from the round-trip spans
+            assert summ["kernel_dispatches"] == {
+                k: v["calls"] for k, v in kernels.items()}
+            assert kernels["key_lookup[pallas]"]["calls"] >= 1
+            assert all(v["mean_us"] > 0 and v["h2d_bytes"] > 0
+                       for v in kernels.values())
+            assert summ["kernel_h2d_bytes"] == sum(
+                v["h2d_bytes"] for v in kernels.values())
+            assert summ["spans_ms"]["llap.read"] > 0
+            # no first-dispatch point events any more
+            assert not [e for e in summ["events"] if e["cat"] == "kernel"]
+            assert validate_chrome_trace(trace.to_chrome()) == []
+
+    def test_h2d_bytes_of_a_key_lookup_call(self):
+        from repro.core.runtime.exec import ExecContext
+
+        ctx = ExecContext(None, None, config={"engine": "pallas"})
+        ctx.trace = QueryTrace("q1")
+        dictionary = np.arange(5, dtype=np.float32)
+        probe = np.arange(1000, dtype=np.float32)
+        ctx.kernel_call("key_lookup", dictionary, probe)
+        # both operands padded to the 1024-row bucket, float32
+        summ = ctx.trace.summary()
+        assert summ["kernel_h2d_bytes"] == 2 * 1024 * 4
+        assert summ["kernels"]["key_lookup[pallas]"]["calls"] == 1
+        # bytes noted with no kernel span open go nowhere
+        ctx.trace = None
+        ctx.kernel_call("key_lookup", dictionary, probe)
+        assert summ["kernel_h2d_bytes"] == 8192
+
+    def test_spans_land_in_the_profiler_trace(self, wh_dir, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        with db.connect(wh_dir, engine="pallas", **TRACED) as conn:
+            _load_star(conn)
+            conn.execute(STAR_SQL).fetchall()  # compile outside the trace
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            try:
+                with jax.profiler.TraceAnnotation("test.window"):
+                    h = conn.execute_async(STAR_SQL.replace("> 10", "> 11"))
+                    h.result().fetchall()
+            finally:
+                jax.profiler.stop_trace()
+            spans = h._task.trace._spans
+        [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+        host = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events]
+        [(w0, w1)] = [(s, e) for n, s, e in host if n == "test.window"]
+        for name in ("kernel.filter_eval", "kernel.key_lookup",
+                     "scan.io_wait", "stage:execute"):
+            traced = [ev for ev in host if ev[0] == name]
+            assert traced, name
+            assert all(w0 <= s <= e <= w1 for _n, s, e in traced), name
+            # one profiler event per live span of the query
+            assert len(traced) == sum(1 for s in spans if s[0] == name)
+        assert not [n for n, *_t in host if n.startswith("bench.")]
+
+
+class TestAdmissionSpans:
+    def test_worker_and_admission_waits_make_up_queue_wait(self, tmp_path):
+        from repro.core.session import Warehouse
+
+        wh = Warehouse(str(tmp_path / "wh"), query_workers=1)
+        with db.connect(warehouse=wh, debug_vertex_delay_s=0.05,
+                        result_cache=False, **TRACED) as conn:
+            _load_events(conn)
+            handles = [conn.execute_async(
+                f"SELECT grp, COUNT(*) FROM ev WHERE k > {i} GROUP BY grp")
+                for i in range(3)]
+            for h in handles:
+                h.result().fetchall()
+            for i, h in enumerate(handles):
+                summ = h._task.trace.summary()
+                worker = summ["spans_ms"]["sched:worker_wait"]
+                admission = summ["spans_ms"]["wlm:admission_wait"]
+                stages = summ["stages_ms"]
+                # between the two waits the worker parses, binds and probes
+                # the result cache; queue_wait_ms counts those stages too
+                pre = stages["parse"] + stages["bind"] + stages["cache_probe"]
+                queue = h.poll()["queue_wait_ms"]
+                assert abs(worker + pre + admission - queue) < 1.0, (
+                    i, worker, pre, admission, queue)
+                assert worker + admission <= queue + 1.0
+                if i:  # queued behind the one worker
+                    assert worker > 40.0, (i, worker)
+        wh.close()
