@@ -387,6 +387,9 @@ class QueryTrace:
         out = []
         for name, cat, t_b, t_e, track, args in spans:
             dur = max(us(t_e) - us(t_b), 0.001)
+            # rounded as ``us`` rounds, so a span's end is the next one's
+            # start to the last bit where the two meet
+            end = round(us(t_b) + dur, 3)
             tid = tid_of(track)
             # sort keys give valid nesting for any properly-nestable set:
             # at equal ts all E before all B, longer B (parents) first,
@@ -394,8 +397,8 @@ class QueryTrace:
             out.append(((us(t_b), 1, -dur),
                         {"ph": "B", "ts": us(t_b), "pid": pid, "tid": tid,
                          "name": name, "cat": cat, "args": args}))
-            out.append(((us(t_b) + dur, 0, dur),
-                        {"ph": "E", "ts": us(t_b) + dur, "pid": pid,
+            out.append(((end, 0, dur),
+                        {"ph": "E", "ts": end, "pid": pid,
                          "tid": tid, "name": name, "cat": cat}))
         for name, cat, ts, track, args in events:
             out.append(((us(ts), 2, 0.0),

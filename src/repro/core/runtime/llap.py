@@ -179,7 +179,11 @@ class LlapIO:
         The I/O elevator fans stripe loads out on the I/O pool and hands each
         column batch to the operator pipeline as soon as it lands — the
         consumer processes stripe N while stripes N+1.. are still loading,
-        instead of waiting for the whole file to decode."""
+        instead of waiting for the whole file to decode.  The file opens
+        once, at the first miss; each miss then reads its chunk by offset
+        and inflates it with the GIL released (``StripeFile``), taking no
+        lock, so the I/O threads read side by side and leave the GIL to the
+        consumer's kernel calls."""
         from ..acid import _bloom_masked
 
         # metadata first — in bulk, before any data I/O (paper §5.1)

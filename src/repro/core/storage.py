@@ -15,8 +15,11 @@ from __future__ import annotations
 import hashlib
 import io
 import json
+import math
 import os
+import struct
 import zipfile
+import zlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -156,33 +159,116 @@ def read_file_meta(path: str) -> FileMeta:
         return FileMeta.from_json(zf.read(_META_KEY).decode())
 
 
+_NPY_V1 = b"\x93NUMPY\x01\x00"
+
+
+def _npy_header(name: str, header: bytes) -> Tuple[np.dtype, tuple]:
+    """The dtype and shape a ``.npy`` 1.0 header gives, for an array
+    ``np.frombuffer`` can view (C order, no objects)."""
+    try:
+        shape, fortran, dtype = np.lib.format.read_array_header_1_0(
+            io.BytesIO(header[len(_NPY_V1):]))
+    except ValueError as e:
+        raise zipfile.BadZipFile(f"Bad .npy header in {name!r}") from e
+    if fortran or dtype.hasobject:
+        raise zipfile.BadZipFile(f"{name!r} is not a C-order array of "
+                                 f"plain values")
+    return dtype, shape
+
+
 class StripeFile:
     """A stripe file opened for column reads.
 
-    A file holds one member per (stripe, column), and opening the zip parses
-    its whole member index, so a file is opened once per scan rather than
-    once per column read (which made a scan quadratic in the stripe count).
-    It opens at the first read, so a scan served from cache opens nothing.
-    Reads from several I/O threads share the handle under one lock.
+    A file holds one member per (stripe, column) as ``write_stripe_file``
+    writes it: a deflated ``.npy`` 1.0 array.  It opens at the first read,
+    once per scan, so a scan served from cache opens nothing; the open
+    parses the zip's central directory, which indexes every member by its
+    offset, sizes and CRC-32, and is the only step under the lock.  A read
+    takes the member's bytes by offset (``os.pread``, no shared file
+    position), inflates them (``zlib`` releases the GIL), checks size and
+    CRC against the directory and views the payload with ``np.frombuffer``,
+    its header parsed once per distinct header.  So I/O threads read side
+    by side.  A member in any other form raises ``zipfile.BadZipFile``.
+    The arrays are read-only: a chunk is shared by every query that scans
+    it.
     """
 
     def __init__(self, path: str):
         self._path = path
+        self._file = None
         self._zf: Optional[zipfile.ZipFile] = None
+        self._headers: Dict[bytes, Tuple[np.dtype, tuple]] = {}
         self._lock = make_lock("storage.stripe_file")
 
+    def _open(self) -> zipfile.ZipFile:
+        if self._zf is None:
+            with self._lock:
+                if self._zf is None:
+                    f = open(self._path, "rb")
+                    try:
+                        zf = zipfile.ZipFile(f)
+                    except BaseException:
+                        f.close()
+                        raise
+                    self._file, self._zf = f, zf
+        return self._zf
+
     def read_column(self, stripe: int, column: str) -> np.ndarray:
-        with self._lock:
-            if self._zf is None:
-                self._zf = zipfile.ZipFile(self._path)
-            payload = self._zf.read(f"s{stripe}/{column}.npy")
-        return np.load(io.BytesIO(payload), allow_pickle=False)
+        info = self._open().getinfo(f"s{stripe}/{column}.npy")
+        return self._view(info.filename, self._member_bytes(info))
+
+    def _member_bytes(self, info: zipfile.ZipInfo) -> bytes:
+        """The member's bytes, inflated and checked against the directory's
+        size and CRC-32, as ``zipfile`` checks them."""
+        if info.flag_bits & 0x1 or info.compress_type != zipfile.ZIP_DEFLATED:
+            raise zipfile.BadZipFile(
+                f"{info.filename!r} is not a plain deflated member")
+        fd = self._file.fileno()
+        # the local header (30 bytes, then name and extra field) and the
+        # data, in one read where the header has no extra field
+        head = 30 + len(info.orig_filename.encode("utf-8"))
+        buf = os.pread(fd, head + info.compress_size, info.header_offset)
+        if buf[:4] != b"PK\x03\x04":
+            raise zipfile.BadZipFile(
+                f"Bad magic number for file header of {info.filename!r}")
+        name_len, extra_len = struct.unpack_from("<HH", buf, 26)
+        start = 30 + name_len + extra_len
+        if start == head and len(buf) == head + info.compress_size:
+            raw = memoryview(buf)[start:]
+        else:
+            raw = os.pread(fd, info.compress_size, info.header_offset + start)
+        try:
+            data = zlib.decompress(raw, -15, info.file_size)
+        except zlib.error as e:
+            raise zipfile.BadZipFile(
+                f"Bad deflate stream for file {info.filename!r}") from e
+        if len(data) != info.file_size or zlib.crc32(data) != info.CRC:
+            raise zipfile.BadZipFile(f"Bad CRC-32 for file {info.filename!r}")
+        return data
+
+    def _view(self, name: str, data: bytes) -> np.ndarray:
+        """The ``.npy`` 1.0 payload as a read-only array over ``data``."""
+        if data[:len(_NPY_V1)] != _NPY_V1:
+            raise zipfile.BadZipFile(f"{name!r} is not a .npy 1.0 array")
+        start = 10 + int.from_bytes(data[8:10], "little")
+        header = data[:start]
+        parsed = self._headers.get(header)
+        if parsed is None:
+            parsed = self._headers[header] = _npy_header(name, header)
+        dtype, shape = parsed
+        count = math.prod(shape)
+        if len(data) - start != count * dtype.itemsize:
+            raise zipfile.BadZipFile(
+                f"{name!r} holds {len(data) - start} payload bytes, not "
+                f"the {count * dtype.itemsize} its header gives")
+        return np.frombuffer(data, dtype, count, start).reshape(shape)
 
     def close(self) -> None:
         with self._lock:
             if self._zf is not None:
                 self._zf.close()
-                self._zf = None
+                self._file.close()
+                self._zf = self._file = None
 
     def __enter__(self) -> "StripeFile":
         return self

@@ -111,6 +111,16 @@ class TestTracedQuery:
                 assert total >= 0 and parts <= total + 0.01, (vid, v)
             assert summ["kernel_dispatches"], "kernels must be counted"
 
+    def test_vertex_phases_nest_where_rounding_meets(self):
+        """A vertex's compute phase ends where its exchange wait begins, to
+        the rounded microsecond, at a clock reading where adding the
+        rounded duration to the rounded start overshoots by one bit."""
+        trace = QueryTrace("q")
+        trace.t0 = 6281.125483753391
+        trace.add_vertex("v2", 6281.307919920278, 0.40652790111616094,
+                         wait_s=0.0938333652930376)
+        assert validate_chrome_trace(trace.to_chrome()) == []
+
     def test_chrome_export_validates(self, wh_dir):
         with db.connect(wh_dir, **TRACED) as conn:
             _load_events(conn)
