@@ -20,26 +20,28 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import harness, loadgen, reference, ssb  # noqa: E402
+from bench import harness, loadgen, reference  # noqa: E402
 
 
-def control_records(tables, sqls) -> list:
+def control_records(dataset, tables, sqls) -> list:
     """The control's answer to each statement, as a run's records."""
-    db = reference.sqlite_reference(tables, control=True, sqls=sqls)
+    db = reference.sqlite_reference(tables, dataset.KEYS, control=True,
+                                    sqls=sqls)
     records = [{"label": "control", "sql": sql,
-                "rows": db.execute(reference.control_sql(sql)).fetchall()}
+                "rows": db.execute(reference.control_sql(
+                    dataset.reference_sql(sql))).fetchall()}
                for sql in sqls]
     db.close()
     return records
 
 
 def read(cell, seed: int, seconds: float) -> dict:
-    config = cell.config
-    tables = ssb.generate(seed, int(config["lineorder_rows"]),
-                          config["dimension_rows"])
-    traffic = loadgen.Traffic(cell.traffic, seed, seconds)
+    config, data = cell.config, cell.dataset
+    tables = data.generate(seed, config)
+    traffic = loadgen.Traffic(cell.traffic, data, seed, seconds)
     sent = {sql for _label, sql in traffic.statements()}
-    records = control_records(tables, sorted(traffic.reference_sample(sent)))
+    records = control_records(data, tables,
+                              sorted(traffic.reference_sample(sent)))
     checks = harness._check(tables, traffic, records, config)
     return {"workload": cell.name, "seed": seed,
             "correct": harness.decide(checks),

@@ -1,9 +1,11 @@
 """One run of one benchmark cell: set-up, measured window, check, report.
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; the
-harness reads ``configs/<config>.json``, ``traffic/<traffic>.json`` and,
-for each per-layer metric, ``metrics/<metric>.py``, all found by name.  It
-holds nothing that belongs to one cell.
+harness reads ``configs/<config>.json``, ``traffic/<traffic>.json``, the
+configuration's dataset module ``<dataset>.py`` (its tables, statements
+and reference keys; SSB's is ``ssb.py``) and, for each per-layer metric,
+``metrics/<metric>.py``, all found by name.  It holds nothing that belongs
+to one cell or one dataset.
 
 A run:
 
@@ -12,10 +14,9 @@ A run:
 2. set-up (``setup_s``, from process start): JAX and the persistent compile
    cache in the checkout, the tables drawn from ``--seed`` and inserted
    through the warehouse's own ACID path, and warm-up: every statement the
-   window can send, run on a small copy of the same tables (the same
-   dimensions, one full stripe and a last stripe as long as the real
-   one's), so that every kernel program the window reaches is compiled or
-   loaded from the cache; where the mix keeps a warm result cache, the
+   window can send, run on the dataset's small copy of the same tables, so
+   that every kernel program the window reaches is compiled or loaded from
+   the cache; where the mix keeps a warm result cache, the
    published queries also run once on the real tables;
 3. the window: the mix's clients through ``repro.api`` for ``--seconds``,
    then the queries in flight; with ``--trace 1`` the warehouse traces
@@ -44,12 +45,12 @@ from . import loadgen
 
 ROOT = Path(__file__).resolve().parents[1]
 STRIPE_ROWS = 8192  # the warehouse's stripe length: the warm copy keeps it
-REHEARSAL_DIMENSION_ROWS = 1000  # a rehearsal's cap on each dimension
 
 
 def resolve_cell(root: Path, name: str) -> SimpleNamespace:
     """The cell ``name`` of ``root/BENCHMARK.json``, with its configuration,
-    traffic mix, metric entries and metric readers loaded by name."""
+    its configuration's dataset module, traffic mix, metric entries and
+    metric readers loaded by name."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -61,11 +62,14 @@ def resolve_cell(root: Path, name: str) -> SimpleNamespace:
 
     bench = root / "bench"
     per_layer = mine(spec["per_layer"])
+    config = json.loads(
+        (bench / "configs" / f"{cell['config']}.json").read_text())
     return SimpleNamespace(
         name=name,
         chips=int(cell["chips"]),
-        config=json.loads(
-            (bench / "configs" / f"{cell['config']}.json").read_text()),
+        config=config,
+        dataset=_load_module(bench / f"{config['dataset']}.py",
+                             "bench_dataset_"),
         traffic=json.loads(
             (bench / "traffic" / f"{cell['traffic']}.json").read_text()),
         end_to_end=mine(spec["end_to_end"]),
@@ -77,11 +81,16 @@ def resolve_cell(root: Path, name: str) -> SimpleNamespace:
 
 def load_reader(path: Path):
     """The ``read(run)`` function of one per-layer metric's file."""
+    return _load_module(path, "bench_metric_").read
+
+
+def _load_module(path: Path, prefix: str):
+    """The Python file at ``path``, loaded by path as a module of its own."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
 
 
 def parse_args(argv):
@@ -91,11 +100,11 @@ def parse_args(argv):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
-                    help="run on the CPU at --rows lineorder rows; never "
+                    help="run on the CPU at --rows fact rows; never "
                          "reports a TPU")
     ap.add_argument("--rows", type=int, default=12_000,
-                    help="lineorder rows of a rehearsal, whose dimensions "
-                         f"hold at most {REHEARSAL_DIMENSION_ROWS} rows")
+                    help="the dataset's fact rows in a rehearsal, which "
+                         "also cuts its other tables as the dataset says")
     return ap.parse_args(argv)
 
 
@@ -143,37 +152,33 @@ def _run(cell, args, jax, peaks, tmp: Path, t_start):
     from repro.core.session import Warehouse
     from repro.kernels import registry
 
-    from . import kernel_bytes, profiling, ssb
+    from . import kernel_bytes, profiling
 
     compiles = profiling.CompileCounter(jax)
-    config, traffic_spec = cell.config, cell.traffic
+    config, traffic_spec, data = cell.config, cell.traffic, cell.dataset
     phases = Phases(t_start, compiles)
-    rows, dims = int(config["lineorder_rows"]), config["dimension_rows"]
-    if args.rehearse:
-        rows = args.rows
-        dims = {k: min(v, REHEARSAL_DIMENSION_ROWS) for k, v in dims.items()}
-    tables = ssb.generate(args.seed, rows, dims)
+    tables = data.generate(args.seed, config,
+                           args.rows if args.rehearse else None)
     phases.done("start_and_generate")
     session = {**config["session"], **traffic_spec.get("session", {})}
-    traffic = loadgen.Traffic(traffic_spec, args.seed, args.seconds)
+    traffic = loadgen.Traffic(traffic_spec, data, args.seed, args.seconds)
     statements = traffic.statements()
 
     # warm-up on a small copy of the same tables: every statement the
     # window can send, then every kernel shape their plans can reach
-    warm_rows = min(rows, STRIPE_ROWS + (rows % STRIPE_ROWS or STRIPE_ROWS))
     small = Warehouse(str(tmp / "warm"), **config["warehouse"])
-    ssb.load(small, ssb.head(tables, warm_rows))
+    data.load(small, data.warm_copy(tables, STRIPE_ROWS))
     phases.done("load_small_copy")
     with _filters_seen(registry) as filters:
         _run_all(db.connect(warehouse=small, **session),
                  [sql for _l, sql in statements])
     small.close()
     phases.done(f"warm_{len(statements)}_statements")
-    shapes = _warm_shapes(registry, filters, dims)
+    shapes = _warm_shapes(registry, filters, data.warm_bound(tables))
     phases.done(f"warm_{shapes}_kernel_shapes")
 
     wh = Warehouse(str(tmp / "wh"), **config["warehouse"])
-    ssb.load(wh, tables)
+    data.load(wh, tables)
     phases.done("load")
     if traffic_spec.get("warm_result_cache"):
         _run_all(db.connect(warehouse=wh, **session),
@@ -299,7 +304,7 @@ ROW_BUCKETS = (1024, 2048, 4096, 8192)  # a stripe holds at most 8192 rows
 GROUP_BUCKETS = (128, 256, 512, 1024)  # groups of one morsel's partial
 
 
-def _warm_shapes(registry, filters, dimension_rows) -> int:
+def _warm_shapes(registry, filters, most: int) -> int:
     """Compile, or load from the cache, every kernel program the window's
     plans can reach.
 
@@ -308,19 +313,19 @@ def _warm_shapes(registry, filters, dimension_rows) -> int:
     a scan before its predicate runs, or a dimension may be probed instead
     of built.  What a plan changes is the row bucket of a call, and which
     side a dictionary or bloom filter is built from; the keys of any build
-    side are at most the largest dimension's rows, and a filter kernel only
-    sees a dimension's rows or a morsel.  So every filter seen is compiled
-    at every row bucket up to the largest dimension's, the dictionary
-    lookup and bloom probe at every row bucket and every build size up to
-    that bound, and the grouped sum, which a morsel whose values float32
-    holds exactly (or an empty one) reaches, at every morsel group bucket.
+    side are at most ``most`` (the dataset's ``warm_bound``: for SSB the
+    largest dimension's rows), and a filter kernel only sees a build side's
+    rows or a morsel.  So every filter seen is compiled at every row bucket
+    up to ``most``'s, the dictionary lookup and bloom probe at every row
+    bucket and every build size up to that bound, and the grouped sum,
+    which a morsel whose values float32 holds exactly (or an empty one)
+    reaches, at every morsel group bucket.
     Returns the number of shapes.
     """
     import numpy as np
 
     from repro.kernels.registry import bucket
 
-    most = max(dimension_rows.values())
     calls = [("filter_eval", ((np.zeros(rows, np.float32),) * ncols, ops,
                               lits))
              for rows in ROW_BUCKETS if rows <= bucket(most)
@@ -379,10 +384,12 @@ def _check(tables, traffic, records, config) -> dict:
     """The numbers compared against the reference, each with its limit."""
     from .reference import compare, sqlite_reference
 
+    data = traffic.dataset
     sent = {r["sql"] for r in records}
     checked = sorted(traffic.reference_sample(sent))
-    ref = sqlite_reference(tables, sqls=checked)
-    want = {sql: ref.execute(sql).fetchall() for sql in checked}
+    ref = sqlite_reference(tables, data.KEYS, sqls=checked)
+    want = {sql: ref.execute(data.reference_sql(sql)).fetchall()
+            for sql in checked}
     ref.close()
     gap, mismatched, compared = 0.0, 0, 0
     for r in records:

@@ -1,6 +1,8 @@
 """The one traffic generator: closed-loop clients driven by a traffic file.
 
-A traffic file (``bench/traffic/<mix>.json``) holds only parameters:
+A traffic file (``bench/traffic/<mix>.json``) holds only parameters; the
+statements come from the configuration's dataset module (its
+``PUBLISHED`` queries, ``TEMPLATES`` and ``draw_params``):
 
 * ``clients``: client threads, each with its own connection, in a closed
   loop (the next query is sent when the last one's rows are in);
@@ -46,25 +48,26 @@ import time
 
 import numpy as np
 
-from . import ssb
-
 QUERY_TIMEOUT_S = 150.0
 
 
 class Traffic:
-    """The request streams of one traffic mix for one seed."""
+    """The request streams of one traffic mix over one dataset for one
+    seed."""
 
-    def __init__(self, spec: dict, seed: int, seconds: float):
+    def __init__(self, spec: dict, dataset, seed: int, seconds: float):
         self.spec = spec
+        self.dataset = dataset
         self.seed = seed
-        self.published = published = ssb.PUBLISHED
+        self.published = published = dataset.PUBLISHED
         self.names = list(spec.get("queries") or sorted(published))
         fresh_share = 1.0 - float(spec["published_share"])
         n_pool = (math.ceil(spec.get("fresh_pool_per_s", 0) * seconds)
                   if fresh_share > 0 else 0)
         rounds = _fresh_rounds(
-            np.random.default_rng(spec.get("fresh_pool_seed", 0)),
-            math.ceil(n_pool / len(ssb.TEMPLATES)), set(published.values()))
+            dataset, np.random.default_rng(spec.get("fresh_pool_seed", 0)),
+            math.ceil(n_pool / len(dataset.TEMPLATES)),
+            set(published.values()))
         rng = np.random.default_rng([seed, 2])
         self.pool = []
         for round_ in rounds:
@@ -128,16 +131,17 @@ class Traffic:
         return (sent & published) | set(fresh)
 
 
-def _fresh_rounds(rng, n: int, exclude: set) -> list:
-    """``n`` rounds of distinct fresh statements, one of each template a
-    round, so that every prefix of a pool taken round by round holds the
-    templates in nearly equal numbers."""
+def _fresh_rounds(dataset, rng, n: int, exclude: set) -> list:
+    """``n`` rounds of distinct fresh statements, one of each of the
+    dataset's templates a round, so that every prefix of a pool taken round
+    by round holds the templates in nearly equal numbers."""
     rounds, seen = [], set(exclude)
     for _ in range(n):
         round_ = []
-        for name in sorted(ssb.TEMPLATES):
+        for name in sorted(dataset.TEMPLATES):
             for _ in range(1000):
-                sql = ssb.TEMPLATES[name].format(**ssb.draw_params(rng))
+                sql = dataset.TEMPLATES[name].format(
+                    **dataset.draw_params(rng))
                 if sql not in seen:
                     break
             else:

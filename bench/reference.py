@@ -22,11 +22,12 @@ import numpy as np
 _BATCH = 200_000
 
 
-def sqlite_reference(tables: dict, control: bool = False,
+def sqlite_reference(tables: dict, keys: dict, control: bool = False,
                      sqls=None) -> sqlite3.Connection:
-    """An in-memory sqlite3 database holding the same generated rows; with
-    ``control``, ``F32SUM`` is registered for :func:`control_sql`.  Given
-    ``sqls``, only the tables and columns they name are loaded."""
+    """An in-memory sqlite3 database holding the same generated rows, each
+    table's ``keys`` column declared its primary key; with ``control``,
+    ``F32SUM`` is registered for :func:`control_sql`.  Given ``sqls``, only
+    the tables and columns they name are loaded."""
     words = (set(re.findall(r"[A-Za-z_][A-Za-z_0-9]*", " ".join(sqls)))
              if sqls is not None else None)
     db = sqlite3.connect(":memory:")
@@ -34,10 +35,9 @@ def sqlite_reference(tables: dict, control: bool = False,
         if words is not None and name not in words:
             continue
         names = [c for c in cols if words is None or c in words]
-        key = next(iter(cols))  # a dimension's first column is its key
         decl = ", ".join(
             f"{c} {_sqlite_type(cols[c])}"
-            + (" PRIMARY KEY" if name != "lineorder" and c == key else "")
+            + (" PRIMARY KEY" if keys.get(name) == c else "")
             for c in names)
         db.execute(f"CREATE TABLE {name} ({decl})")
         insert = f"INSERT INTO {name} VALUES ({', '.join('?' * len(names))})"

@@ -5,9 +5,11 @@
     JAX_PLATFORMS=cpu python bench/run.py --workload <cell> --seed 1 \
         --seconds 2 --trace 0 --rehearse --rows 12000
 
-Without ``--rehearse`` a host whose JAX finds no TPU, or fewer chips than
-the cell asks for, fails at once and prints no result.  Everything runs in
-this one process.  See ``bench/harness.py`` for what a run does.
+A rehearsal runs on the CPU at ``--rows`` of the dataset's fact rows, its
+other tables cut as the dataset module says.  Without ``--rehearse`` a host
+whose JAX finds no TPU, or fewer chips than the cell asks for, fails at
+once and prints no result.  Everything runs in this one process.  See
+``bench/harness.py`` for what a run does.
 """
 import time
 

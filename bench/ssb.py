@@ -11,6 +11,11 @@ Numbers follow ``ssb-dbgen``'s output: prices and costs are whole cents,
 ``lo_discount`` (0-10) and ``lo_tax`` (0-8) whole percents, and dates keys
 of the form ``yyyymmdd``.  What the specification leaves to the generator
 is listed under ``assumed`` in each configuration's file.
+
+A configuration names this module as its ``"dataset"``; the harness asks it
+for everything that is SSB's: ``generate``, ``warm_copy``, ``warm_bound``,
+``load``, ``PUBLISHED``, ``TEMPLATES``, ``draw_params``, ``KEYS`` and
+``reference_sql``.
 """
 from __future__ import annotations
 
@@ -52,6 +57,7 @@ SEGMENTS = ("AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD")
 PRIORITIES = ("1-URGENT", "2-HIGH", "3-MEDIUM", "4-NOT SPECIFIED", "5-LOW")
 SHIPMODES = ("REG AIR", "AIR", "RAIL", "SHIP", "TRUCK", "MAIL", "FOB")
 DATE_ROWS = 2556  # ssb-dbgen's date table: 1992-01-01 to 1998-12-30
+REHEARSAL_DIMENSION_ROWS = 1000  # a rehearsal's cap on each dimension
 LAST_ORDER_DAY = np.datetime64("1998-08-02")  # TPC-H's end date less 151 days
 
 
@@ -135,12 +141,18 @@ def retail_cents(partkey: np.ndarray) -> np.ndarray:
     return 90000 + (partkey // 10) % 20001 + 100 * (partkey % 1000)
 
 
-def generate(seed: int, lineorder_rows: int, dims: dict) -> dict:
-    """``{table: {column: ndarray}}`` for one seed.
-
-    ``dims`` gives the rows of ``customer``, ``supplier`` and ``part``;
-    ``date`` always holds SSB's 2,556 days.
+def generate(seed: int, config: dict, rehearse_rows=None) -> dict:
+    """``{table: {column: ndarray}}`` for one seed, at the configuration's
+    ``lineorder_rows`` and ``dimension_rows`` (of ``customer``,
+    ``supplier`` and ``part``); a rehearsal draws ``rehearse_rows``
+    lineorder rows and at most :data:`REHEARSAL_DIMENSION_ROWS` rows a
+    dimension.  ``date`` always holds SSB's 2,556 days.
     """
+    lineorder_rows = int(config["lineorder_rows"])
+    dims = config["dimension_rows"]
+    if rehearse_rows is not None:
+        lineorder_rows = rehearse_rows
+        dims = {k: min(v, REHEARSAL_DIMENSION_ROWS) for k, v in dims.items()}
     rng = np.random.default_rng([seed, 0])
     n_cust, n_supp, n_part = dims["customer"], dims["supplier"], dims["part"]
     tables = {"date": _dates()}
@@ -246,6 +258,34 @@ def head(tables: dict, rows: int) -> dict:
     out = dict(tables)
     out["lineorder"] = {c: v[:rows] for c, v in tables["lineorder"].items()}
     return out
+
+
+def warm_copy(tables: dict, stripe_rows: int) -> dict:
+    """The tables the warm-up runs on: the same dimensions, and lineorder's
+    first full stripe and a last stripe as long as the real one's."""
+    rows = len(tables["lineorder"]["lo_orderkey"])
+    return head(tables, min(rows, stripe_rows
+                            + (rows % stripe_rows or stripe_rows)))
+
+
+def warm_bound(tables: dict) -> int:
+    """The most keys any build side can hold: the rows of the largest of
+    customer, supplier and part.  ``date``'s fixed 2,556 days are left out:
+    part is larger at every configuration's size, and a rehearsal, which
+    caps the others at 1,000, has no compile cache to fill."""
+    return max(len(next(iter(tables[t].values())))
+               for t in ("customer", "supplier", "part"))
+
+
+# each dimension's key, which the reference declares its primary key
+KEYS = {"date": "d_datekey", "customer": "c_custkey",
+        "supplier": "s_suppkey", "part": "p_partkey"}
+
+
+def reference_sql(sql: str) -> str:
+    """The reference's text of a statement: SSB's SQL runs on sqlite3 as it
+    is."""
+    return sql
 
 
 def _ddl(name: str, cols: dict) -> str:

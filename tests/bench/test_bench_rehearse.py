@@ -157,7 +157,7 @@ def test_control_in_the_programs_place_is_not_correct(cell, monkeypatch,
     def with_control_answers(tables, traffic, records, config):
         done = [r for r in records if "error" not in r]
         answers = {r["sql"]: r["rows"] for r in control.control_records(
-            tables, sorted({r["sql"] for r in done}))}
+            traffic.dataset, tables, sorted({r["sql"] for r in done}))}
         for r in done:
             r["rows"] = answers[r["sql"]]
         return check(tables, traffic, records, config)

@@ -112,7 +112,7 @@ DIMS = {"customer": 1000, "supplier": 200, "part": 1000}
 
 
 def _tables(seed=5, rows=3000):
-    return ssb.generate(seed, rows, DIMS)
+    return ssb.generate(seed, {"lineorder_rows": rows, "dimension_rows": DIMS})
 
 
 def test_generator_is_a_function_of_the_seed():
@@ -130,8 +130,8 @@ def test_generator_keeps_the_specification_shapes():
     """SSB's columns, domains and sizes: 17 lineorder columns, 2,556
     yyyymmdd dates, 250 cities, 25 categories, 1,000 brands, orders of 1 to
     7 lines, and every constant of the published queries in its domain."""
-    t = ssb.generate(3, 20_000, {"customer": 30_000, "supplier": 2000,
-                                 "part": 200_000})
+    t = ssb.generate(3, {"lineorder_rows": 20_000, "dimension_rows": {
+        "customer": 30_000, "supplier": 2000, "part": 200_000}})
     lo, d, p, c = t["lineorder"], t["date"], t["part"], t["customer"]
     assert len(lo) == 17 and len(d) == 17 and len(p) == 9 and len(c) == 8
     assert {k: len(next(iter(v.values()))) for k, v in t.items()} == {
@@ -182,7 +182,7 @@ def test_control_fails_the_limit_where_the_reference_passes():
     limits = json.loads((ROOT / "bench/configs/ssb-sf1.json").read_text())[
         "limits"]
     tables = _tables(rows=40_000)
-    ref = reference.sqlite_reference(tables, control=True)
+    ref = reference.sqlite_reference(tables, ssb.KEYS, control=True)
     mismatched = 0
     for sql in ssb.PUBLISHED.values():
         want = ref.execute(sql).fetchall()
@@ -196,14 +196,14 @@ def test_control_fails_the_limit_where_the_reference_passes():
 def test_reference_loads_only_what_the_statements_name():
     tables = _tables(rows=500)
     sql = ssb.PUBLISHED["q1.1"]
-    db = reference.sqlite_reference(tables, sqls=[sql])
+    db = reference.sqlite_reference(tables, ssb.KEYS, sqls=[sql])
     names = {r[0] for r in db.execute(
         "select name from sqlite_master where type = 'table'")}
     assert names == {"lineorder", "date"}
     cols = [r[1] for r in db.execute("pragma table_info(lineorder)")]
     assert cols == ["lo_orderdate", "lo_quantity", "lo_extendedprice",
                     "lo_discount"]
-    full = reference.sqlite_reference(tables)
+    full = reference.sqlite_reference(tables, ssb.KEYS)
     assert db.execute(sql).fetchall() == full.execute(sql).fetchall()
 
 
@@ -257,7 +257,7 @@ def test_traffic_sends_the_same_requests_on_every_seed():
     spec = json.loads((ROOT / "bench/traffic/dashboard.json").read_text())
     sent = []
     for seed in (5, 2**31 + 12345):
-        traffic = Traffic(spec, seed, 51)
+        traffic = Traffic(spec, ssb, seed, 51)
         firsts = [[label for label, _sql in
                    (next(s) for _ in range(10))]
                   for s in map(traffic.stream, range(spec["clients"]))]
