@@ -146,6 +146,14 @@ def note_h2d(*arrays) -> None:
         span.h2d_bytes += sum(int(a.nbytes) for a in arrays)
 
 
+def note_kernel_args(**args) -> None:
+    """Add ``args`` to the kernel span open on this thread (what the call
+    chose, such as a program's path); dropped when none is."""
+    span = getattr(_tls, "kernel", None)
+    if span is not None:
+        span.args.update(args)
+
+
 # ---------------------------------------------------- profiler annotations
 def _profiler_annotation(name: str):
     """An entered ``jax.profiler.TraceAnnotation`` named ``name`` while a

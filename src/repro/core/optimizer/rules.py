@@ -141,6 +141,8 @@ class Optimizer:
         if not isinstance(node, P.Filter):
             return node
         pred = fold_constants(node.predicate)
+        if not isinstance(pred, A.Lit):
+            pred = _factor_disjunctions(pred, node.input)
         if isinstance(pred, A.Lit):
             if pred.value:
                 return node.input
@@ -514,13 +516,67 @@ def _has_pred(node: P.PlanNode, pred: A.Expr) -> bool:
     return False
 
 
+def _split_disjuncts(e: A.Expr) -> List[A.Expr]:
+    if isinstance(e, A.BinOp) and e.op == "OR":
+        return _split_disjuncts(e.left) + _split_disjuncts(e.right)
+    return [e]
+
+
+def _disjoin(es: List[A.Expr]) -> A.Expr:
+    out = es[0]
+    for e in es[1:]:
+        out = A.BinOp("OR", out, e)
+    return out
+
+
+def _factor_disjunctions(pred: A.Expr, below: P.PlanNode) -> A.Expr:
+    """Rewrite each ``OR`` conjunct of ``pred`` so that more of it can join
+    or filter early.
+
+    A conjunct common to every disjunct is taken out of the ``OR``
+    (``(k = j AND x) OR (k = j AND y)`` is ``k = j AND (x OR y)``), so an
+    equi-join key hidden in it becomes a key.  And where every disjunct
+    filters one column by values, the ``OR`` of those filters is added
+    beside it (it is implied), unless ``below`` already applies it, so a
+    scan can take it.
+    """
+    out: List[A.Expr] = []
+    for c in split_conjuncts(pred):
+        arms = _split_disjuncts(c)
+        if len(arms) < 2:
+            out.append(c)
+            continue
+        parts = [split_conjuncts(a) for a in arms]
+        common = [x for x in parts[0]
+                  if all(any(y.key() == x.key() for y in p) for p in parts[1:])]
+        keys = {x.key() for x in common}
+        rests = [[y for y in p if y.key() not in keys] for p in parts]
+        out.extend(common)
+        if any(not r for r in rests):  # one arm is the common part alone
+            continue
+        out.append(_disjoin([conjoin(r) for r in rests]))
+        cols = [{next(iter(expr_columns(y))) for y in r
+                 if len(expr_columns(y)) == 1 and _is_value_pred(y)}
+                for r in rests]
+        for col in sorted(set.intersection(*cols)):
+            implied = _disjoin([conjoin([
+                y for y in r if expr_columns(y) == {col} and _is_value_pred(y)])
+                for r in rests])
+            if not _has_pred(below, implied) and all(
+                    x.key() != implied.key() for x in out):
+                out.append(implied)
+    return conjoin(out)
+
+
 def _collect_join_tree(node, rels: list, edges: list, residuals: list) -> bool:
     """Flatten a tree of inner joins into relations + equi edges.
 
     Returns False if the subtree contains anything but inner joins (outer
     joins constrain ordering and are left untouched).
     """
-    if isinstance(node, P.Join) and node.kind == "inner":
+    # a cross join inside an inner-join tree is one more relation pair with
+    # no edge: flattening it lets the order join along the predicates
+    if isinstance(node, P.Join) and node.kind in ("inner", "cross"):
         if node.residual is not None:
             residuals.extend(split_conjuncts(node.residual))
         ok_l = _collect_join_tree(node.left, rels, edges, residuals)

@@ -454,6 +454,9 @@ class ExecuteStage(Stage):
             if slot is not None:
                 q.info["wlm_pool"] = slot.pool
             q.batch = self._run_dag(q, qid, slot)
+            ctx = q.exec_ctx
+            if ctx is not None and ctx.kernel_fallbacks:
+                q.info["kernel_fallbacks"] = dict(ctx.kernel_fallbacks)
             if q.task is not None:
                 # fallback for paths that produced no live chunk stream (a
                 # barrier-mode run, or a consumer that attached late): the

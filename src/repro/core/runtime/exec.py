@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ...analysis.lockdep import make_lock
 from ..acid import AcidTable
 from ..bloomfilter import BloomFilter
 from ..metastore import Metastore, Snapshot, WriteIdList
@@ -70,6 +71,9 @@ class ExecContext:
         self.subplan_cache: Dict[str, VectorBatch] = {}
         self.runtime_filter_cache: Dict[str, dict] = {}
         self._widlists: Dict[str, WriteIdList] = {}
+        # kernel-shaped calls the numpy path ran instead, per kernel
+        self.kernel_fallbacks: Dict[str, int] = {}
+        self._fallback_lock = make_lock("exec.kernel_fallbacks")
 
     def widlist(self, table: str) -> WriteIdList:
         if table not in self._widlists:
@@ -78,6 +82,17 @@ class ExecContext:
 
     def record(self, node: P.PlanNode, rows: int) -> None:
         self.op_stats[node.digest()] = rows
+
+    def kernel_fallback(self, name: str) -> None:
+        """Count one call site that asked for kernel ``name`` (the engine is
+        ``pallas`` or ``ref``) and ran numpy instead: its operands are not
+        exact in the kernel's float32, or lie past its limits.  Per query in
+        ``kernel_fallbacks`` and warehouse-wide as
+        ``kernels.fallback.<name>``."""
+        with self._fallback_lock:
+            self.kernel_fallbacks[name] = self.kernel_fallbacks.get(name, 0) + 1
+        if self.metrics is not None:
+            self.metrics.inc(f"kernels.fallback.{name}")
 
     def kernel_call(self, name: str, *args):
         """Run the registry kernel ``name`` under this query's engine; the
@@ -193,10 +208,15 @@ def _f_length(args):
 
 @scalar_fn("substr")
 def _f_substr(args):
-    start = int(args[1][0]) - 1
-    ln = int(args[2][0]) if len(args) > 2 else None
     s = args[0].astype(str)
-    return np.array([x[start:start + ln] if ln else x[start:] for x in s])
+    if len(s) == 0:
+        return s
+    start = max(int(args[1][0]) - 1, 0)
+    ln = int(args[2][0]) if len(args) > 2 else None
+    if start == 0 and ln is not None:
+        return s.astype(f"U{max(ln, 1)}")  # a cast to a width keeps a prefix
+    return np.array([x[start:start + ln] if ln is not None else x[start:]
+                     for x in s])
 
 
 @scalar_fn("coalesce")
@@ -209,11 +229,19 @@ def _f_coalesce(args):
 
 
 @scalar_fn("extract")
-def _f_extract(args):  # extract(year, datestr) simplified
-    part = args[0]
-    vals = args[1].astype(str)
-    idx = {"year": slice(0, 4), "month": slice(5, 7), "day": slice(8, 10)}[str(part[0]).lower()]
-    return np.array([int(v[idx]) if len(v) >= 10 else -1 for v in vals], dtype=np.int64)
+def _f_extract(args):
+    """``extract(field, date)`` of ISO ``yyyy-mm-dd`` strings; -1 where a
+    value is not one."""
+    part, vals = args
+    if len(vals) == 0:
+        return np.empty(0, dtype=np.int64)
+    lo, hi = {"year": (0, 4), "month": (5, 7),
+              "day": (8, 10)}[str(part[0]).lower()]
+    chars = np.ascontiguousarray(vals.astype("U10")).view(np.uint32)
+    digits = chars.reshape(len(vals), 10)[:, lo:hi].astype(np.int64) - 48
+    ok = ((digits >= 0) & (digits <= 9)).all(axis=1)
+    value = digits @ (10 ** np.arange(hi - lo - 1, -1, -1))
+    return np.where(ok, value, -1)
 
 
 @scalar_fn("year")
@@ -413,17 +441,22 @@ class _BuildTable:
             rc = inv if rc is None else rc * k + inv
         self.order = np.argsort(rc, kind="stable")
         self.rc_sorted = rc[self.order]
+        # per key column, the kernel's float32 form of the dictionary, or
+        # None where float32 cannot hold it; made on the first probe, so
+        # that a chunk's lookup costs O(chunk), not O(dictionary)
+        self._kernel_dicts: Dict[int, Optional[np.ndarray]] = {}
 
     def probe_codes(self, lb: VectorBatch) -> np.ndarray:
         """Combined build codes for a probe chunk; -1 marks no-match rows."""
         lc, valid = None, None
-        for (uniq, cast, k), lk in zip(self.keys, self.left_keys):
+        for i, ((uniq, cast, k), lk) in enumerate(zip(self.keys,
+                                                      self.left_keys)):
             v = lb.cols[lk]
             if cast is str:
                 v = v.astype(str)
             elif cast is float:
                 v = v.astype(np.float64)
-            codes = self._lookup(uniq, v)
+            codes = self._lookup(i, uniq, v)
             ok = codes >= 0
             valid = ok if valid is None else (valid & ok)
             c = np.where(ok, codes, 0)
@@ -432,17 +465,29 @@ class _BuildTable:
             return np.full(lb.num_rows, -1, dtype=np.int64)
         return np.where(valid, lc, np.int64(-1))
 
-    def _lookup(self, uniq: np.ndarray, vals: np.ndarray) -> np.ndarray:
+    def _kernel_dict(self, i: int, uniq: np.ndarray) -> Optional[np.ndarray]:
+        if i not in self._kernel_dicts:
+            u32 = None
+            if uniq.dtype.kind in "iuf":
+                u32 = uniq.astype(np.float32)
+                if not np.array_equal(u32.astype(uniq.dtype), uniq):
+                    u32 = None
+            self._kernel_dicts[i] = u32
+        return self._kernel_dicts[i]
+
+    def _lookup(self, i: int, uniq: np.ndarray,
+                vals: np.ndarray) -> np.ndarray:
         if len(uniq) == 0:
             return np.full(len(vals), -1, dtype=np.int64)
-        if (self.ctx.engine != "auto" and uniq.dtype.kind in "iuf"
-                and vals.dtype.kind in "iuf"):
-            # kernel contract is float32: only when the cast round-trips
-            u32, v32 = uniq.astype(np.float32), vals.astype(np.float32)
-            if (np.array_equal(u32.astype(uniq.dtype), uniq)
-                    and np.array_equal(v32.astype(vals.dtype), vals)):
-                return self.ctx.kernel_call("key_lookup", u32,
-                                            v32).astype(np.int64)
+        if self.ctx.engine != "auto":
+            # kernel contract is float32: only when the casts round-trip
+            u32 = self._kernel_dict(i, uniq)
+            if u32 is not None and vals.dtype.kind in "iuf":
+                v32 = vals.astype(np.float32)
+                if np.array_equal(v32.astype(vals.dtype), vals):
+                    return self.ctx.kernel_call("key_lookup", u32,
+                                                v32).astype(np.int64)
+            self.ctx.kernel_fallback("key_lookup")
         idx = np.minimum(np.searchsorted(uniq, vals), len(uniq) - 1)
         found = uniq[idx] == vals
         return np.where(found, idx, -1).astype(np.int64)
@@ -713,6 +758,7 @@ class Executor:
                 cols, ops, lits = compiled
                 return self.ctx.kernel_call("filter_eval", cols, ops,
                                             lits).astype(bool)
+            self.ctx.kernel_fallback("filter_eval")
         return eval_expr(predicate, b, self.ctx).astype(bool)
 
     def _stream_project(self, node: P.Project):
@@ -793,7 +839,10 @@ class Executor:
             build_chunks.append(rb_chunk)
         rb = VectorBatch.concat(build_chunks)
 
-        if node.kind == "cross":
+        if node.kind == "cross" or (node.kind == "inner"
+                                    and not node.left_keys):
+            # an inner join with no equi keys pairs every row (its residual
+            # filters the pairs)
             for lb in self.stream(node.left):
                 li = np.repeat(np.arange(lb.num_rows), rb.num_rows)
                 ri = np.tile(np.arange(rb.num_rows), lb.num_rows)
@@ -827,15 +876,16 @@ class Executor:
             counts = np.where(lc < 0, 0, hi - lo)
 
             if node.kind in ("semi", "anti"):
-                mask = counts > 0 if node.kind == "semi" else counts == 0
-                if node.residual is not None and node.kind == "semi":
+                # a row matches when some build row has its keys and, with a
+                # residual, passes it on the joined pair
+                hit = counts > 0
+                if node.residual is not None and hit.any():
                     li, ri = _expand_matches(lo, counts, probe.order)
                     joined = _concat_sides(lb.take(li), rb.take(ri))
                     ok = eval_expr(node.residual, joined, self.ctx).astype(bool)
-                    good_left = np.unique(li[ok])
-                    mask = np.zeros(lb.num_rows, dtype=bool)
-                    mask[good_left] = True
-                yield lb.select(mask)
+                    hit = np.zeros(lb.num_rows, dtype=bool)
+                    hit[li[ok]] = True
+                yield lb.select(hit if node.kind == "semi" else ~hit)
                 continue
 
             li, ri = _expand_matches(lo, counts, probe.order)
@@ -1057,19 +1107,13 @@ class Executor:
         if spec.fn not in ("sum", "count", "min", "max") or spec.distinct \
                 or vals is None:
             return None
-        if ng <= 0 or vals.dtype.kind not in "iufb":
-            return None
-        if vals.size >= (1 << 24):
-            # the kernel's float32 accumulators stop being exact integers at
-            # 2^24, so COUNTs (and the row-bounded sums below) could silently
-            # round; beyond that the numpy path runs
+        kernel = "hash_group_minmax" if spec.fn in ("min", "max") \
+            else "hash_group"
+        if ng <= 0 or vals.dtype.kind not in "iufb" \
+                or not _float32_exact_agg(spec.fn, vals):
+            self.ctx.kernel_fallback(kernel)
             return None
         f32 = vals.astype(np.float32)
-        # the kernel accumulates in float32: only take this path when the
-        # cast is value-preserving (also rejects NaN/NULL-carrying columns,
-        # whose skip semantics the kernel does not implement)
-        if not np.array_equal(f32.astype(vals.dtype), vals):
-            return None
         if spec.fn in ("min", "max"):
             mins, maxs = self.ctx.kernel_call(
                 "hash_group_minmax", codes.astype(np.int32), f32, int(ng))
@@ -1080,11 +1124,6 @@ class Executor:
             if vals.dtype.kind in "iu" and not np.isnan(out).any():
                 return out.astype(np.int64)
             return out
-        if spec.fn == "sum" and vals.dtype.kind in "iu" and vals.size:
-            # integer sums must stay exact: every partial sum is an integer
-            # bounded by sum(|v|), so < 2^24 keeps float32 accumulation exact
-            if float(np.abs(vals.astype(np.int64)).sum()) >= float(1 << 24):
-                return None
         sums, counts = self.ctx.kernel_call(
             "hash_group", codes.astype(np.int32), f32, int(ng))
         if spec.fn == "count":
@@ -1317,6 +1356,45 @@ def _eval_window(wf: A.WindowFunc, b: VectorBatch, ctx) -> np.ndarray:
 
 
 _KERNEL_FILTER_OPS = {"<": 0, "<=": 1, ">": 2, ">=": 3, "=": 4, "!=": 5}
+
+
+# a float32 accumulator holds every integer, and every multiple of 2^-k,
+# whose magnitude in units of 2^-k stays below 2^24
+_F32_EXACT = float(1 << 24)
+
+
+def _fraction_bits(vals: np.ndarray) -> Optional[int]:
+    """The fewest binary fraction digits that write every value of ``vals``
+    (0 for integers); None past float32's 24 significant bits."""
+    scaled = vals.astype(np.float64)
+    for k in range(25):
+        if np.array_equal(scaled, np.floor(scaled)):
+            return k
+        scaled = scaled * 2.0
+    return None
+
+
+def _float32_exact_agg(fn: str, vals: np.ndarray) -> bool:
+    """Whether the float32 kernels aggregate ``vals`` exactly: each value
+    survives the cast, COUNTs stay below 2^24, and for a SUM every partial
+    sum does too.  A partial sum is a multiple of 2^-k, k the values' most
+    fraction bits, bounded by sum(|v|): below 2^24 units of 2^-k it is exact
+    (also rejects NaN/NULL-carrying columns, whose skip semantics the kernel
+    does not implement)."""
+    if vals.size >= (1 << 24):
+        # the kernel's float32 accumulators stop being exact integers at
+        # 2^24, so COUNTs (and the row-bounded sums below) could round
+        return False
+    f32 = vals.astype(np.float32)
+    if not np.array_equal(f32.astype(vals.dtype), vals):
+        return False
+    if fn != "sum" or vals.dtype.kind == "b" or not vals.size:
+        return True
+    if vals.dtype.kind in "iu":
+        return float(np.abs(vals.astype(np.int64)).sum()) < _F32_EXACT
+    k = _fraction_bits(vals)
+    return k is not None and \
+        float(np.abs(vals.astype(np.float64)).sum()) * 2.0 ** k < _F32_EXACT
 
 
 def _compile_kernel_filter(pred: A.Expr, b: VectorBatch):

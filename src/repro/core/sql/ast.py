@@ -157,6 +157,18 @@ class Param(Expr):
 
 
 @dataclass(frozen=True)
+class Interval(Expr):
+    """``INTERVAL 'n' DAY|MONTH|YEAR``: only as the right operand of ``+`` or
+    ``-`` on a DATE literal, which the binder folds to a date literal."""
+
+    value: int
+    unit: str  # day | month | year
+
+    def key(self) -> str:
+        return f"INTERVAL '{self.value}' {self.unit.upper()}"
+
+
+@dataclass(frozen=True)
 class Star(Expr):
     table: Optional[str] = None
 
@@ -227,6 +239,7 @@ class TableRef:
 class SubqueryRef:
     query: "Select"
     alias: str
+    columns: Optional[List[str]] = None  # ``AS alias (c1, c2, ...)``
 
 
 @dataclass

@@ -12,6 +12,8 @@ Responsibilities:
 """
 from __future__ import annotations
 
+import calendar
+import datetime
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -25,11 +27,28 @@ class BindError(Exception):
 
 
 class Scope:
-    def __init__(self, tables: Dict[str, List[str]], parent: Optional["Scope"] = None):
+    def __init__(self, tables: Dict[str, List[str]], parent: Optional["Scope"] = None,
+                 internal: Optional[Dict[str, str]] = None):
         # alias -> list of raw column names
         self.tables = tables
         self.parent = parent
+        # alias -> the alias the plan names its columns by, where they
+        # differ: a subquery's table whose alias an enclosing query also
+        # uses gets a fresh one, so the two never share a column name
+        self.internal = internal or {}
         self.correlated_uses: List[str] = []  # qualified outer columns we touched
+
+    def _q(self, table: str, col: str) -> str:
+        return f"{self.internal.get(table, table)}.{col}"
+
+    def visible(self, alias: str) -> bool:
+        """Whether ``alias`` names a table here or in an enclosing scope."""
+        scope = self
+        while scope is not None:
+            if alias in scope.tables:
+                return True
+            scope = scope.parent
+        return False
 
     def resolve(self, col: A.Col) -> Tuple[str, int]:
         """Return (qualified_name, level); level 0 = local, 1+ = outer."""
@@ -39,7 +58,7 @@ class Scope:
             while scope is not None:
                 if col.table in scope.tables:
                     if col.name in scope.tables[col.table]:
-                        return f"{col.table}.{col.name}", level
+                        return scope._q(col.table, col.name), level
                     raise BindError(f"column {col.name} not in {col.table}")
                 scope, level = scope.parent, level + 1
             raise BindError(f"unknown table alias {col.table}")
@@ -50,7 +69,7 @@ class Scope:
             if len(hits) > 1:
                 raise BindError(f"ambiguous column {col.name} ({hits})")
             if hits:
-                return f"{hits[0]}.{col.name}", level
+                return scope._q(hits[0], col.name), level
             scope, level = scope.parent, level + 1
         raise BindError(f"unknown column {col.name}")
 
@@ -58,7 +77,7 @@ class Scope:
         out = []
         for t, cols in self.tables.items():
             if alias is None or t == alias:
-                out.extend(f"{t}.{c}" for c in cols)
+                out.extend(self._q(t, c) for c in cols)
         return out
 
 
@@ -190,8 +209,20 @@ class Binder:
             bound_projs.append((be, alias or _derive_name(be, i)))
 
         having = None
-        if sel.having is not None:
+        # scalar subqueries in HAVING compare with aggregates: each is joined
+        # to the aggregate's output, under a name the predicate refers to
+        having_subs: List[Tuple[A.SubqueryExpr, str]] = []
+        if sel.having is not None and _find_subquery(sel.having) is None:
             plan, having = self._bind_expr_unnesting(plan, scope, sel.having)
+        elif sel.having is not None:
+            hv = sel.having
+            while (sub := _find_subquery(hv)) is not None:
+                if sub.kind != "scalar":
+                    raise BindError("only scalar subqueries allowed in HAVING")
+                having_subs.append((sub, self._fresh("hq")))
+                hv = _replace_subquery(hv, sub, A.Col(having_subs[-1][1]))
+            having = self._bind_expr_except(hv, scope,
+                                            {n for _, n in having_subs})
 
         order_bound: List[Tuple[A.Expr, bool]] = []
         for e, desc in sel.order_by:
@@ -231,6 +262,18 @@ class Binder:
             bound_projs = [(rewrite(be), n) for be, n in bound_projs]
             having = rewrite(having) if having is not None else None
             order_bound = [(rewrite(be), d) for be, d in order_bound]
+
+        for sub, name in having_subs:
+            subplan, subnames = self._bind_query(sub.query, scope)
+            subplan, corr, rest = _extract_correlation(subplan, scope)
+            if corr or rest:
+                raise BindError("a subquery in HAVING must not be correlated")
+            kept = [(A.Col(_base(n), _qual(n)) if "." in n else A.Col(n), n)
+                    for n in plan.output_names()]
+            val = subnames[0]
+            plan = P.Project(P.Join(plan, subplan, "cross", [], []), kept + [
+                (A.Col(_base(val), _qual(val)) if "." in val else A.Col(val),
+                 name)])
 
         if having is not None:
             plan = P.Filter(plan, having)
@@ -313,25 +356,35 @@ class Binder:
             if desc.is_mv and desc.mv_sql is None:
                 raise BindError(f"materialized view {node.name} has no definition")
             cols = [c for c, _ in desc.schema]
+            plan_alias = self._plan_alias(alias, outer)
             scan: P.PlanNode
             if desc.handler:
-                scan = P.FederatedScan(desc, alias, cols)
+                scan = P.FederatedScan(desc, plan_alias, cols)
             else:
-                scan = P.Scan(desc, alias, cols)
-            return scan, Scope({alias: cols}, outer)
+                scan = P.Scan(desc, plan_alias, cols)
+            return scan, Scope({alias: cols}, outer, {alias: plan_alias})
         if isinstance(node, A.SubqueryRef):
             subplan, names = self._bind_query(node.query, outer)
             base_names = [_base(n) for n in names]
+            if node.columns is not None:
+                if len(node.columns) != len(names):
+                    raise BindError(
+                        f"{node.alias} names {len(node.columns)} columns; "
+                        f"its query has {len(names)}")
+                base_names = list(node.columns)
+            plan_alias = self._plan_alias(node.alias, outer)
             proj = P.Project(
                 subplan,
                 [(A.Col(_base(n), _qual(n)) if "." in n else A.Col(n),
-                  f"{node.alias}.{b}") for n, b in zip(names, base_names)],
+                  f"{plan_alias}.{b}") for n, b in zip(names, base_names)],
             )
-            return proj, Scope({node.alias: base_names}, outer)
+            return proj, Scope({node.alias: base_names}, outer,
+                               {node.alias: plan_alias})
         if isinstance(node, A.JoinRef):
             lplan, lscope = self._bind_from(node.left, outer)
             rplan, rscope = self._bind_from(node.right, outer)
-            merged = Scope({**lscope.tables, **rscope.tables}, outer)
+            merged = Scope({**lscope.tables, **rscope.tables}, outer,
+                           {**lscope.internal, **rscope.internal})
             if node.condition is None:
                 return (
                     P.Join(lplan, rplan, "cross" if node.kind == "cross" else "inner",
@@ -348,6 +401,13 @@ class Binder:
                 kind = "left"
             return P.Join(lplan, rplan, kind, keys_l, keys_r, residual), merged
         raise BindError(f"unsupported FROM element {type(node).__name__}")
+
+    def _plan_alias(self, alias: str, outer: Optional[Scope]) -> str:
+        """The alias a FROM item's columns go by in the plan: its own, or a
+        fresh one where an enclosing query's table has the same."""
+        if outer is not None and outer.visible(alias):
+            return self._fresh(alias)
+        return alias
 
     # ======================================================================
     # predicates & subquery unnesting
@@ -367,8 +427,9 @@ class Binder:
         subscope_parent = scope
         subplan, subnames = self._bind_query(sub.query, subscope_parent)
         # correlation: equality conjuncts referencing outer columns were bound
-        # inside subplan Filters; extract them into join keys.
-        subplan, corr_pairs = _extract_correlation(subplan, scope)
+        # inside subplan Filters; extract them into join keys, and the other
+        # conjuncts that reference outer columns into the join's residual.
+        subplan, corr_pairs, corr_rest = _extract_correlation(subplan, scope)
 
         if sub.kind in ("in", "exists"):
             lkeys, rkeys = [c[0] for c in corr_pairs], [c[1] for c in corr_pairs]
@@ -386,13 +447,20 @@ class Binder:
                 raise BindError("subquery must be a top-level conjunct")
             if isinstance(conjunct, A.UnOp) and conjunct.op == "NOT":
                 kind = "semi" if kind == "anti" else "anti"
+            inner = set(subplan.output_names())
+            keep = list(rkeys)
+            for c in corr_rest:
+                keep += [n for n in sorted(_expr_cols(c) & inner)
+                         if n not in keep]
             build = self._distinct(P.Project(
                 subplan,
-                [(A.Col(_base(n), _qual(n)), n) for n in rkeys],
-            )) if rkeys else subplan
-            return P.Join(plan, build, kind, lkeys, rkeys)
+                [(A.Col(_base(n), _qual(n)), n) for n in keep],
+            )) if keep else subplan
+            return P.Join(plan, build, kind, lkeys, rkeys, conjoin(corr_rest))
 
         if sub.kind == "scalar":
+            if corr_rest:
+                raise BindError("a scalar subquery correlates by equality only")
             # comparison against a (possibly correlated) scalar subquery
             return self._join_scalar_subquery(
                 plan, scope, conjunct, sub, subplan, subnames, corr_pairs,
@@ -436,13 +504,24 @@ class Binder:
         if sub.kind != "scalar":
             raise BindError("only scalar subqueries allowed in this context")
         subplan, subnames = self._bind_query(sub.query, scope)
-        subplan, corr = _extract_correlation(subplan, scope)
+        subplan, corr, rest = _extract_correlation(subplan, scope)
+        if rest:
+            raise BindError("a scalar subquery correlates by equality only")
         plan2, ref = self._join_scalar_subquery(
             plan, scope, e, sub, subplan, subnames, corr, as_filter=False
         )
         new_e = _replace_subquery(e, sub, ref)
         # rebind remaining structure (ref resolves via plan outputs)
         return plan2, self._bind_expr_loose(new_e, plan2, scope)
+
+    def _bind_expr_except(self, e, scope, names: set):
+        """Bind against scope, leaving the unqualified columns ``names``."""
+        if isinstance(e, A.Col) and e.table is None and e.name in names:
+            return e
+        if isinstance(e, (A.Col, A.SubqueryExpr, A.Param)):
+            return self._bind_expr(e, scope)
+        return _rebuild(e, [self._bind_expr_except(c, scope, names)
+                            for c in e.children()])
 
     def _bind_expr_loose(self, e, plan, scope):
         """Bind against scope but let already-qualified synthetic cols pass."""
@@ -470,6 +549,10 @@ class Binder:
             return e  # handled by unnesting paths
         if isinstance(e, A.Param):
             return e  # bound at execution time from ExecContext.params
+        if isinstance(e, A.BinOp) and isinstance(e.right, A.Interval):
+            return _fold_interval(self._bind_expr(e.left, scope), e.op, e.right)
+        if isinstance(e, A.Interval):
+            raise BindError(f"{e.key()} stands only after DATE 'x' + or -")
         return _rebuild(e, [self._bind_expr(c, scope) for c in e.children()])
 
     # ======================================================================
@@ -631,6 +714,26 @@ def _rebuild(e: A.Expr, new_children: List[A.Expr]) -> A.Expr:
     return type(e)(**kwargs)
 
 
+def _fold_interval(left: A.Expr, op: str, iv: A.Interval) -> A.Lit:
+    """``DATE 'x' + or - INTERVAL 'n' unit`` as the date literal it names.
+    Month and year steps keep the day of the month, or fall back to the
+    month's last day (``1995-01-31 + 1 month`` is ``1995-02-28``)."""
+    if op not in ("+", "-") or not (isinstance(left, A.Lit)
+                                    and isinstance(left.value, str)):
+        raise BindError(f"{iv.key()} is added to or taken from a DATE literal")
+    try:
+        d = datetime.date.fromisoformat(left.value)
+    except ValueError:
+        raise BindError(f"{left.value!r} is not a date") from None
+    n = iv.value if op == "+" else -iv.value
+    if iv.unit == "day":
+        return A.Lit((d + datetime.timedelta(days=n)).isoformat())
+    month = d.year * 12 + d.month - 1 + (n if iv.unit == "month" else 12 * n)
+    year, month = divmod(month, 12)
+    day = min(d.day, calendar.monthrange(year, month + 1)[1])
+    return A.Lit(datetime.date(year, month + 1, day).isoformat())
+
+
 def _find_subquery(e: A.Expr) -> Optional[A.SubqueryExpr]:
     for node in A.walk(e):
         if isinstance(node, A.SubqueryExpr):
@@ -668,8 +771,10 @@ def _classify_join_condition(cond, lnames, rnames):
 
 
 def _extract_correlation(subplan: P.PlanNode, outer_scope: Scope):
-    """Pull equality conjuncts that reference outer columns out of the
-    subquery plan's filters; return (new_plan, [(outer_q, inner_q), ...])."""
+    """Pull the conjuncts that reference outer columns out of the subquery
+    plan's filters; return ``(new_plan, [(outer_q, inner_q), ...], rest)``:
+    the equalities of an outer and an inner column as pairs, and the other
+    such conjuncts, which the caller evaluates on the joined rows."""
     outer_cols = set()
     scope = outer_scope
     while scope is not None:
@@ -677,6 +782,7 @@ def _extract_correlation(subplan: P.PlanNode, outer_scope: Scope):
         scope = scope.parent
 
     pairs: List[Tuple[str, str]] = []
+    rest: List[A.Expr] = []
 
     def visit(node: P.PlanNode) -> P.PlanNode:
         for i, child in enumerate(node.inputs):
@@ -696,6 +802,10 @@ def _extract_correlation(subplan: P.PlanNode, outer_scope: Scope):
                     if rq in outer_cols and lq in inner_names and rq not in inner_names:
                         pairs.append((rq, lq))
                         continue
+                cols = _expr_cols(c)
+                if (cols & outer_cols) - inner_names and cols & inner_names:
+                    rest.append(c)
+                    continue
                 keep.append(c)
             if not keep:
                 return node.input
@@ -707,13 +817,17 @@ def _extract_correlation(subplan: P.PlanNode, outer_scope: Scope):
     # Correlated aggregates: if the subquery aggregates globally but we pulled
     # correlation keys out, re-group by the inner correlation keys so the join
     # preserves per-outer-row semantics.
-    if pairs:
+    if pairs or rest:
         inner_keys = [p[1] for p in pairs]
+        # inner columns the join reads: keys, and those of the residual
+        carried = inner_keys + sorted(
+            {n for c in rest for n in _expr_cols(c)} - outer_cols)
 
         def fix_agg(node):
             for i, child in enumerate(node.inputs):
                 node.inputs[i] = fix_agg(child)
-            if isinstance(node, P.Aggregate) and not node.group_keys:
+            if isinstance(node, P.Aggregate) and not node.group_keys \
+                    and inner_keys:
                 avail = set(node.input.output_names())
                 missing = [k for k in inner_keys if k not in avail]
                 if missing and isinstance(node.input, P.Project):
@@ -730,13 +844,17 @@ def _extract_correlation(subplan: P.PlanNode, outer_scope: Scope):
                 # ensure correlation keys survive the projection above the agg
                 have = {n for _, n in node.exprs}
                 child_names = set(node.input.output_names())
-                for k in inner_keys:
+                for k in carried:
                     if k not in have and k in child_names:
                         node.exprs = node.exprs + [(A.Col(_base(k), _qual(k)), k)]
             return node
 
         newplan = fix_agg(newplan)
-    return newplan, pairs
+    return newplan, pairs, rest
+
+
+def _expr_cols(e: A.Expr) -> set:
+    return {x.qualified for x in A.walk(e) if isinstance(x, A.Col)}
 
 
 def _replace_by_key(e: A.Expr, mapping: Dict[str, str]) -> A.Expr:

@@ -8,6 +8,8 @@ and the workload-management DDL of §5.2.
 """
 from __future__ import annotations
 
+import copy
+import datetime
 import re
 from typing import List, Optional, Tuple
 
@@ -79,6 +81,9 @@ class Parser:
         self.toks = tokenize(sql)
         self.i = 0
         self._next_param = 0  # ordinal for '?' placeholders (qmark style)
+        # WITH names in scope -> (column names or None, query); each
+        # reference becomes a derived table of its own copy of the query
+        self._ctes: dict = {}
 
     # -- token helpers --------------------------------------------------------
     def peek(self, offset: int = 0) -> Token:
@@ -110,6 +115,10 @@ class Parser:
             return True
         return False
 
+    def _at_op(self, op: str) -> bool:
+        t = self.peek()
+        return t.kind == "op" and t.value == op
+
     def expect_op(self, op: str) -> None:
         if not self.accept_op(op):
             raise SyntaxError(f"expected {op!r} at {self.peek()!r}")
@@ -135,7 +144,7 @@ class Parser:
             self.next()
             analyze = self.accept_kw("analyze")
             return A.Explain(self._statement(), analyze)
-        if self.at_kw("select") or (self.peek().kind == "op" and self.peek().value == "("):
+        if self.at_kw("select", "with") or self._at_op("("):
             return self._select_with_setops()
         if self.at_kw("insert"):
             return self._insert()
@@ -174,6 +183,8 @@ class Parser:
 
     # -- SELECT / set ops -----------------------------------------------------
     def _select_with_setops(self):
+        if self.at_kw("with"):
+            return self._with()
         left = self._select_core()
         while self.at_kw("union", "intersect", "except"):
             kind = self.next().value
@@ -188,6 +199,34 @@ class Parser:
             if self.accept_kw("limit"):
                 left.limit = int(self.next().value)
         return left
+
+    def _with(self):
+        """``WITH name [(cols)] AS (query) [, ...] query`` (not recursive):
+        each name is in scope for the later definitions and the body."""
+        self.expect_kw("with")
+        outer = dict(self._ctes)
+        try:
+            while True:
+                name = self.ident()
+                cols = self._name_list() if self._at_op("(") else None
+                self.expect_kw("as")
+                self.expect_op("(")
+                query = self._select_with_setops()
+                self.expect_op(")")
+                self._ctes[name.lower()] = (cols, query)
+                if not self.accept_op(","):
+                    break
+            return self._select_with_setops()
+        finally:
+            self._ctes = outer
+
+    def _name_list(self) -> List[str]:
+        self.expect_op("(")
+        names = [self.ident()]
+        while self.accept_op(","):
+            names.append(self.ident())
+        self.expect_op(")")
+        return names
 
     def _select_core(self) -> A.Select:
         if self.accept_op("("):
@@ -304,7 +343,8 @@ class Parser:
             self.expect_op(")")
             self.accept_kw("as")
             alias = self.ident()
-            return A.SubqueryRef(q, alias)
+            cols = self._name_list() if self._at_op("(") else None
+            return A.SubqueryRef(q, alias, cols)
         # one-, two-, or three-part names: table | catalog.table |
         # catalog.schema.table (federated catalogs, paper §6)
         parts = [self.ident()]
@@ -317,6 +357,10 @@ class Parser:
             alias = self.ident()
         elif self.peek().kind == "ident":
             alias = self.ident()
+        if len(parts) == 1 and parts[0].lower() in self._ctes:
+            cols, query = self._ctes[parts[0].lower()]
+            return A.SubqueryRef(copy.deepcopy(query), alias or parts[0],
+                                 list(cols) if cols else None)
         if len(parts) == 1:
             return A.TableRef(parts[0], alias)
         if len(parts) == 2:
@@ -738,6 +782,13 @@ class Parser:
             return p
         # identifier: column, qualified column, star, or function call
         name = self.ident()
+        low = name.lower()
+        if low == "date" and self.peek().kind == "str":
+            return A.Lit(_iso_date(self.next().value))
+        if low == "interval" and self.peek().kind == "str":
+            return self._interval()
+        if low in ("extract", "substring") and self.accept_op("("):
+            return self._extract() if low == "extract" else self._substring()
         if self.accept_op("("):
             distinct = self.accept_kw("distinct")
             args: List[A.Expr] = []
@@ -773,6 +824,44 @@ class Parser:
             return A.Col(self.ident(), table=name)
         return A.Col(name)
 
+    def _interval(self) -> A.Expr:
+        """``INTERVAL 'n' DAY|MONTH|YEAR [(precision)]``."""
+        text = self.next().value.strip()
+        if not re.fullmatch(r"[+-]?\d+", text):
+            raise SyntaxError(f"interval {text!r} is not a whole number")
+        unit = self.ident().lower()
+        if unit not in ("day", "month", "year"):
+            raise SyntaxError(f"unsupported interval unit {unit!r}")
+        if self.accept_op("("):
+            self.next()  # leading field precision: no bearing on the value
+            self.expect_op(")")
+        return A.Interval(int(text), unit)
+
+    def _extract(self) -> A.Expr:
+        """``EXTRACT(YEAR|MONTH|DAY FROM x)``, after its ``(``."""
+        part = self.ident().lower()
+        if part not in ("year", "month", "day"):
+            raise SyntaxError(f"unsupported EXTRACT field {part!r}")
+        self.expect_kw("from")
+        e = self._expr()
+        self.expect_op(")")
+        return A.Func("extract", (A.Lit(part), e))
+
+    def _substring(self) -> A.Expr:
+        """``SUBSTRING(x FROM a [FOR b])`` or ``SUBSTRING(x, a[, b])``, after
+        its ``(``: the same function as ``substr``."""
+        args = [self._expr()]
+        if self.accept_kw("from"):
+            args.append(self._expr())
+            if self.peek().kind == "ident" and self.peek().value.lower() == "for":
+                self.next()
+                args.append(self._expr())
+        else:
+            while self.accept_op(","):
+                args.append(self._expr())
+        self.expect_op(")")
+        return A.Func("substr", tuple(args))
+
     def _case(self) -> A.Expr:
         self.expect_kw("case")
         whens = []
@@ -788,6 +877,15 @@ class Parser:
         otherwise = self._expr() if self.accept_kw("else") else None
         self.expect_kw("end")
         return A.Case(tuple(whens), otherwise)
+
+
+def _iso_date(text: str) -> str:
+    """A DATE literal's value: its ISO ``yyyy-mm-dd`` string, which orders
+    and compares as the dates do."""
+    try:
+        return datetime.date.fromisoformat(text.strip()).isoformat()
+    except ValueError:
+        raise SyntaxError(f"invalid DATE literal {text!r}") from None
 
 
 def parse(sql: str) -> A.Statement:

@@ -140,6 +140,46 @@ def test_key_lookup_duplicates_at_block_edges():
     assert got[probe == sorted_vals[1016]][0] == 1016
 
 
+def _threshold_sizes():
+    from repro.kernels.key_lookup.key_lookup import COMPARE_MAX_ENTRIES
+
+    t = COMPARE_MAX_ENTRIES
+    # one entry under the threshold, at it (both compare-and-reduce), one
+    # over it and several 1024-entry tiles over it (the blocked search)
+    return [t - 1, t, t + 1, 5 * t + 3 * 1024 + 17]
+
+
+@pytest.mark.parametrize("g", _threshold_sizes())
+def test_key_lookup_pallas_matches_ref_around_the_threshold(g):
+    """Misses, repeated probes, runs of equal entries across row and tile
+    edges, and both ends of the dictionary, on each side of the size where
+    the lookup changes program."""
+    from repro.kernels.key_lookup.key_lookup import lookup_path
+    from repro.kernels.key_lookup.ops import key_lookup
+    from repro.kernels.key_lookup.ref import key_lookup_ref
+    from repro.kernels.registry import bucket
+
+    rng = np.random.default_rng(g)
+    sorted_vals = np.sort(rng.choice(4 * g, g, replace=False)).astype(
+        np.float32)
+    sorted_vals[120:136] = sorted_vals[120]  # a run across a lane row
+    sorted_vals[1016:1040] = sorted_vals[1016]  # and across a tile edge
+    ends = sorted_vals[[0, 1, -2, -1]]
+    probe = np.concatenate([
+        ends, ends - 1, ends + 1, sorted_vals[[120, 1016]],
+        rng.choice(sorted_vals, 900),  # hits, many repeated
+        rng.integers(-3, 4 * g + 3, 1100).astype(np.float32)])  # misses too
+    got = np.asarray(key_lookup(sorted_vals, probe, engine="pallas"))
+    exp = np.asarray(key_lookup_ref(jnp.asarray(sorted_vals),
+                                    jnp.asarray(probe)))
+    np.testing.assert_array_equal(got, exp)
+    assert got[4] == -1 and got[:4].tolist() == [0, 1, g - 2, g - 1]
+    assert got[12] == 120 and got[13] == 1016
+    assert (got == -1).any() and len(np.unique(got[14:914])) < 900
+    want_path = "compare" if g <= _threshold_sizes()[1] else "blocked"
+    assert lookup_path(bucket(g)) == want_path
+
+
 @pytest.mark.parametrize("engine", ["pallas", "ref"])
 def test_key_lookup_empty_dictionary(engine):
     from repro.kernels.key_lookup.ops import key_lookup
